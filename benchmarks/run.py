@@ -28,6 +28,8 @@ def main(argv=None) -> None:
                          "Perfetto) covering the selected benchmarks")
     args = ap.parse_args(argv)
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.profile_dir:
         import jax
         jax.profiler.start_trace(args.profile_dir)

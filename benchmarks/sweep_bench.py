@@ -77,11 +77,8 @@ def _bench_sweep(cfgs, data, nets, pinit, reps=3):
         return eng
 
     def cache_size():
-        try:
-            return int(SweepEngine.from_configs(
-                cfgs, data, nets)._block._cache_size())
-        except AttributeError:                 # older jit wrapper
-            return -1
+        return int(SweepEngine.from_configs(
+            cfgs, data, nets)._block._cache_size())
 
     before = cache_size()
     t0 = time.time()
@@ -90,7 +87,7 @@ def _bench_sweep(cfgs, data, nets, pinit, reps=3):
     # compiles THIS grid added to the shared sweep-program cache (the
     # jit wrapper is shared across grids with the same static config,
     # so the absolute cache size counts other grids' shapes too)
-    n_compiles = cache_size() - before if before >= 0 else -1
+    n_compiles = cache_size() - before
     best = first
     for _ in range(reps):
         t0 = time.time()

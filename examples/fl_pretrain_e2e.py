@@ -22,6 +22,9 @@ from repro.configs.base import TrainConfig, get_config
 from repro.core.tra import TRAConfig
 from repro.launch.fl_train import make_fl_train_step
 from repro.models import transformer as T
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=200)

@@ -15,6 +15,9 @@ from repro.core.server import FederatedServer, FLConfig
 from repro.core.tra import TRAConfig
 from repro.data.synthetic import generate_synthetic
 from repro.network.trace import sample_networks
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 data = generate_synthetic(rng, n_clients=30, alpha=1.0, beta=1.0)

@@ -13,6 +13,9 @@ from repro.configs.base import get_config
 from repro.launch.steps import make_serve_step
 from repro.models import decode as D
 from repro.models import transformer as T
+from repro.utils.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 BATCH, PROMPT, NEW = 2, 8, 12
 

@@ -25,12 +25,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                  # jax >= 0.5 exports it at top level
-    from jax import shard_map
-except ImportError:                   # jax 0.4.x (this repo's pin)
-    from jax.experimental.shard_map import shard_map
 
 PACKET_F = 256
 

@@ -12,6 +12,7 @@ module and nowhere else.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 # Guard for aggregate denominators (sums of client weights or of masked
 # per-coordinate weights). Must be far below any realistic weight sum so
@@ -59,3 +60,25 @@ def resolve_interpret(interpret: bool | None = None,
     if interpret is not None:
         return interpret
     return resolve_lowering(gpu_lowerable=gpu_lowerable)[1]
+
+
+def tile_budget(table: dict, what: str):
+    """The backend's row of a kernel's tiling table. A backend with no
+    row is an error: there is no tiling to fall back to."""
+    backend = jax.default_backend()
+    if backend not in table:
+        raise ValueError(f"{what}: no block sizes for backend {backend!r}"
+                         f" (have {sorted(table)})")
+    return table[backend]
+
+
+def round_up(n: int, k: int) -> int:
+    return -(-n // k) * k
+
+
+def pad_to(a, shape):
+    """Zero-pad ``a`` up to ``shape`` at the end of every axis (blocks
+    that do not divide a dimension pad it to whole blocks)."""
+    if a.shape == tuple(shape):
+        return a
+    return jnp.pad(a, [(0, n - k) for k, n in zip(a.shape, shape)])

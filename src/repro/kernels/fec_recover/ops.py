@@ -47,22 +47,12 @@ def fec_recover(mask, parity, *, group: int, impl: str | None = None,
     repaired (C, P) f32 mask — a group with exactly one data loss and
     a delivered parity has that loss flipped back to delivered.
     """
-    C, P = mask.shape
+    P = mask.shape[1]
     if resolved_impl(impl) == "kernel":
         gn = parity.shape[1]
         pad = gn * group - P
         mp = jnp.pad(mask, ((0, 0), (0, pad)), constant_values=1.0)
-        bc = block_c if block_c is not None \
-            else (64 if C % 64 == 0 else 8 if C % 8 == 0
-                  else _largest_divisor_leq(C, 8))
-        out = fec_recover_call(mp, parity, group=group, block_c=bc,
+        out = fec_recover_call(mp, parity, group=group, block_c=block_c,
                                interpret=interpret)
         return out[:, :P]
     return fec_recover_ref(mask, parity, group)
-
-
-def _largest_divisor_leq(n: int, k: int) -> int:
-    for d in range(min(n, k), 0, -1):
-        if n % d == 0:
-            return d
-    return 1

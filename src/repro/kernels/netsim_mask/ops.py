@@ -59,20 +59,6 @@ def ge_packet_mask(u_t, u_e, s0, p_gb, p_bg, h_g, h_b, *,
     p_gb, p_bg, h_g, h_b = _c(p_gb), _c(p_bg), _c(h_g), _c(h_b)
     s0 = s0.astype(jnp.int32)
     if resolved_impl(impl) == "kernel":
-        # client block: prefer 64/8 rows (f32 sublane-aligned on TPU),
-        # clamped to a divisor of C so ANY cohort size lowers — an
-        # explicit kernel request is never silently downgraded to the
-        # reference.
-        bc = block_c if block_c is not None \
-            else (64 if C % 64 == 0 else 8 if C % 8 == 0
-                  else _largest_divisor_leq(C, 8))
         return netsim_mask_call(u_t, u_e, s0, p_gb, p_bg, h_g, h_b,
-                                block_c=bc, interpret=interpret)
+                                block_c=block_c, interpret=interpret)
     return ge_mask_ref(u_t, u_e, s0, p_gb, p_bg, h_g, h_b)
-
-
-def _largest_divisor_leq(n: int, k: int) -> int:
-    for d in range(min(n, k), 0, -1):
-        if n % d == 0:
-            return d
-    return 1

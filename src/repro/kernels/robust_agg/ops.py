@@ -78,8 +78,8 @@ def _pack_rows(rows, P: int, F: int):
 def _kernel_dispatch(has_ef: bool, has_trim: bool, per_coord: bool,
                      trim_k: int, block_p, interpret, eps: float):
     """custom_vmap-wrapped kernel call for one static signature (cf.
-    uplink_fused.ops): plain calls hit the single-scenario grid; a
-    vmapped call (the sweep engine) hits the scenario-batched grid."""
+    uplink_fused.ops): plain calls run the grid at S = 1; a vmapped
+    call (the sweep engine) hits the scenario-batched grid."""
     kw = dict(trim_k=trim_k, block_p=block_p, interpret=interpret,
               eps=eps, per_coord=per_coord)
 

@@ -39,7 +39,7 @@ from repro.kernels.common import DENOM_EPS, RATE_EPS
 from repro.kernels.tra_agg.ops import DEBIAS_MODES
 from repro.kernels.uplink_fused.ref import uplink_ref
 from repro.kernels.uplink_fused.uplink_fused import (
-    pick_blocks, uplink_fused_batched_call, uplink_fused_call)
+    uplink_fused_batched_call, uplink_fused_call)
 
 UPLINK_IMPLS = ("auto", "kernel", "ref")
 
@@ -77,8 +77,8 @@ def debias_client_scale(weights, *, mode, kept=None, sufficient=None,
 def _kernel_dispatch(has_ef: bool, per_coord: bool, want_ssq: bool,
                      block_p, block_c, interpret, eps: float):
     """custom_vmap-wrapped kernel call for one static signature: plain
-    calls hit the single-scenario grid; a vmapped call (the sweep
-    engine) dispatches to the scenario-batched grid."""
+    calls run the grid at S = 1; a vmapped call (the sweep engine)
+    dispatches to the scenario-batched grid."""
     kw = dict(want_ssq=want_ssq, per_coord=per_coord, block_p=block_p,
               block_c=block_c, interpret=interpret, eps=eps)
 
@@ -158,19 +158,19 @@ def uplink_round(xp, pkt_mask, weights, *, mode: str, d_up: int,
     ef_p = _pack_rows(ef_rows, P, F) if ef_rows is not None else None
 
     if resolved_impl(impl) == "kernel":
-        bp, bc = pick_blocks(C, P, block_p, block_c)
         x = xp if stream_dtype is None else xp.astype(stream_dtype)
         ef_k = ef_p if ef_p is None or stream_dtype is None \
             else ef_p.astype(stream_dtype)
         call = _kernel_dispatch(ef_k is not None, per_coord, want_ssq,
-                                bp, bc, interpret, float(DENOM_EPS))
+                                block_p, block_c, interpret,
+                                float(DENOM_EPS))
         args = (x, pkt_mask.astype(jnp.float32), q_c.astype(jnp.float32),
                 w_or_den)
         outs = list(call(*args, ef_k) if ef_k is not None
                     else call(*args))
         agg = outs.pop(0)
         ef_out = outs.pop(0) if ef_k is not None else None
-        ssq = outs.pop(0).sum(axis=-1) if want_ssq else None
+        ssq = outs.pop(0) if want_ssq else None
     else:
         # ref path honours the stream contract too: inputs rounded to
         # the stream dtype (uplink_ref upcasts to f32 to accumulate),

@@ -21,12 +21,15 @@ scatter source) reads the (C, P, F) tensor >= 3 times and writes the
 EF-adjusted intermediate once; this kernel reads x and ef once each and
 writes only the true outputs.
 
-Tiling: grid (P//bp, C//bc) — C innermost, so on TPU (sequential grid)
-the (bp, F) fp32 aggregate accumulator and the (bp,) denominator live in
-VMEM scratch across the client loop while the output tile's block index
-stays fixed; the aggregate is divided and written once on the last
-client step. EF tiles stream through: each grid cell reads a
-(bc, bp, F) tile of x/ef and writes the matching ef_out tile.
+Tiling: grid (S, P//bp, C//bc) — C innermost, so on TPU (sequential
+grid) the (bp, F) fp32 aggregate accumulator and the (bp,) denominator
+live in VMEM scratch across the client loop while the output tile's
+block index stays fixed; the aggregate is divided and written once on
+the last client step. EF tiles stream through: each grid cell reads a
+(bc, bp, F) tile of x/ef and writes the matching ef_out tile. The mask
+enters transposed, (P, C), and the squared norms leave per packet in
+the same layout, so their blocks are legal at any packet block of 8
+rows (see ``pick_blocks``).
 
 bf16-stream / fp32-accumulate contract: x and ef may arrive as bf16
 (halving HBM traffic); every tile is upcast to fp32 on load, the
@@ -34,10 +37,10 @@ aggregate and ssq accumulate in fp32, and ef_out is written back in the
 stream dtype. The f32 default is bit-exact against ref.py (locked by
 tests/test_uplink_fused.py).
 
-``uplink_fused_batched_call`` is the scenario-batched variant: a leading
-S grid axis over (S, C, P, F) inputs, same body, so `core/sweep.py`
-grids ride the SAME kernel — ops.py wires it in as the jax.vmap rule of
-the single-scenario call (`jax.custom_batching.custom_vmap`).
+``uplink_fused_batched_call`` is the kernel: a leading S grid axis over
+(S, C, P, F) inputs, so `core/sweep.py` grids ride the SAME kernel —
+ops.py wires it in as the jax.vmap rule of the single-scenario call
+(`jax.custom_batching.custom_vmap`), which is the batched call at S = 1.
 """
 from __future__ import annotations
 
@@ -48,50 +51,46 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import DENOM_EPS, resolve_interpret
+from repro.kernels.common import (DENOM_EPS, pad_to, resolve_interpret,
+                                  round_up, tile_budget)
 
-# Autotune tables: backend -> (block_p thresholds, block_c thresholds),
-# each a ((dim_at_least, block), ...) ladder. Preferences are clamped to
-# the largest divisor of the actual dim, so any (C, P) lowers. TPU rows
-# keep (bc, bp, F) tiles in the 0.5-2 MiB VMEM sweet spot at F = 256;
-# CPU rows only matter for interpret-mode emulation speed.
-_AUTOTUNE = {
-    "tpu": ((((512, 64), (128, 32), (32, 16), (0, 8))),
-            (((64, 16), (16, 8), (0, 4)))),
-    "gpu": ((((512, 32), (0, 16))),
-            (((32, 8), (0, 4)))),
-    "cpu": ((((256, 16), (0, 8))),
-            (((16, 8), (0, 4)))),
-}
-
-
-def _largest_divisor_leq(n: int, k: int) -> int:
-    for d in range(min(n, k), 0, -1):
-        if n % d == 0:
-            return d
-    return 1
+# Per-backend budget for one (bc, bp, F) tile of x (bytes); None = the
+# whole (C, P) in one block. Mosaic's tiling rule shapes every block
+# here: the last two dims of a block are multiples of (8, 128) or span
+# the array. Blocks are x/ef (bc, bp, F), the transposed mask and the
+# per-packet ssq (bp, bc), the scales (bc, 1) and agg (bp, F), so
+# bp is P or a multiple of 8 and bc is C or a multiple of 128; the
+# wrapper zero-pads C and P up to whole blocks. On TPU ~1 MiB f32 tiles
+# keep x, ef and ef_out double-buffered well inside the scoped VMEM. On
+# CPU the kernel only runs in interpret mode (tests), where one block
+# is fastest and reduces in the reference's order.
+_TILE_BYTES = {"tpu": 1 << 20, "cpu": None}
 
 
-def pick_blocks(C: int, P: int, block_p: int | None = None,
-                block_c: int | None = None):
-    """(block_p, block_c) from the backend autotune table, clamped to
-    divisors of the actual dims; explicit arguments override the table
-    (still clamped)."""
-    tp, tc = _AUTOTUNE.get(jax.default_backend(), _AUTOTUNE["cpu"])
-    if block_p is None:
-        block_p = next(b for t, b in tp if P >= t)
+def pick_blocks(C: int, P: int, F: int = 256, itemsize: int = 4,
+                block_p: int | None = None, block_c: int | None = None):
+    """(block_p, block_c) for a (C, P, F) upload tensor: the whole array
+    when it fits the backend's tile budget, else full-cohort (or
+    128-client) blocks of as many 8-packet rows as fit. Explicit
+    arguments override the choice; the call pads to whole blocks."""
+    budget = tile_budget(_TILE_BYTES, "uplink_fused")
     if block_c is None:
-        block_c = next(b for t, b in tc if C >= t)
-    return _largest_divisor_leq(P, block_p), _largest_divisor_leq(C, block_c)
+        block_c = C if budget is None or C <= 128 else 128
+    if block_p is None:
+        row = block_c * F * itemsize
+        block_p = P if budget is None or P * row <= budget \
+            else max(8, budget // row // 8 * 8)
+    return block_p, block_c
 
 
 def _body(x, ef, m, q, wden, den, agg_at, efo_at, ssq_at,
           acc_ref, den_acc_ref, ci, *, nc, per_coord, eps, out_dtype):
-    """One grid cell; shared by the single-scenario and scenario-batched
-    kernels (which differ only in the leading-axis slicing of refs)."""
+    """One grid cell. ``m`` arrives as the (bp, bc) tile of the
+    transposed mask (lane-dense over clients) and is turned back here."""
     x = x.astype(jnp.float32)
     if ef is not None:
         x = x + ef.astype(jnp.float32)            # EF re-inject, fp32
+    m = m.T                                       # (bc, bp)
     wm = m * q                                    # (bc, bp)
 
     @pl.when(ci == 0)
@@ -105,7 +104,7 @@ def _body(x, ef, m, q, wden, den, agg_at, efo_at, ssq_at,
     if efo_at is not None:
         efo_at[...] = (x * (1.0 - m[..., None])).astype(out_dtype)
     if ssq_at is not None:
-        ssq_at[...] = ((x * x).sum(-1) * m).sum(-1)[:, None]
+        ssq_at[...] = ((x * x).sum(-1) * m).T     # per packet, (bp, bc)
 
     @pl.when(ci == nc - 1)
     def _finish():
@@ -116,7 +115,7 @@ def _body(x, ef, m, q, wden, den, agg_at, efo_at, ssq_at,
         agg_at[...] = acc_ref[...] / d
 
 
-def _unpack(refs, has_ef, per_coord, want_ssq):
+def _kernel(*refs, nc, per_coord, has_ef, want_ssq, eps, out_dtype):
     """Split the flat pallas ref list back into named operands."""
     it = iter(refs)
     x = next(it)
@@ -128,22 +127,6 @@ def _unpack(refs, has_ef, per_coord, want_ssq):
     efo = next(it) if has_ef else None
     ssq = next(it) if want_ssq else None
     acc, den_acc = next(it), next(it)
-    return x, ef, m, q, wden, den, agg, efo, ssq, acc, den_acc
-
-
-def _kernel_single(*refs, nc, per_coord, has_ef, want_ssq, eps, out_dtype):
-    x, ef, m, q, wden, den, agg, efo, ssq, acc, den_acc = _unpack(
-        refs, has_ef, per_coord, want_ssq)
-    _body(x[...], ef[...] if ef is not None else None, m[...], q[...],
-          wden[...] if wden is not None else None,
-          den[0, 0] if den is not None else None,
-          agg, efo, ssq, acc, den_acc, pl.program_id(1),
-          nc=nc, per_coord=per_coord, eps=eps, out_dtype=out_dtype)
-
-
-def _kernel_batched(*refs, nc, per_coord, has_ef, want_ssq, eps, out_dtype):
-    x, ef, m, q, wden, den, agg, efo, ssq, acc, den_acc = _unpack(
-        refs, has_ef, per_coord, want_ssq)
     _body(x[0], ef[0] if ef is not None else None, m[0], q[0],
           wden[0] if wden is not None else None,
           den[0, 0, 0] if den is not None else None,
@@ -153,11 +136,78 @@ def _kernel_batched(*refs, nc, per_coord, has_ef, want_ssq, eps, out_dtype):
           nc=nc, per_coord=per_coord, eps=eps, out_dtype=out_dtype)
 
 
+def uplink_fused_batched_call(x, m, q, w_or_den, *, ef=None,
+                              want_ssq=False, block_p: int | None = None,
+                              block_c: int | None = None,
+                              interpret: bool | None = None,
+                              eps: float = DENOM_EPS, per_coord: bool):
+    """Scenario-batched megakernel over (S, C, P, F) inputs: grid
+    (S, P//bp, C//bc). Shapes follow ``uplink_fused_call`` with a
+    leading S on every operand (``w_or_den`` is (S, C) when
+    ``per_coord``, else (S,) ready scalars)."""
+    S, C, P, F = x.shape
+    bp, bc = pick_blocks(C, P, F, x.dtype.itemsize, block_p, block_c)
+    Cp, Pp = round_up(C, bc), round_up(P, bp)
+    gp, nc = Pp // bp, Cp // bc
+    interpret = resolve_interpret(interpret)
+    has_ef = ef is not None
+    # padded clients carry zero uploads, mask and weights; padded
+    # packets are undelivered zeros: both add exact zeros and are
+    # sliced off below
+    tile = pl.BlockSpec((1, bc, bp, F), lambda s, p, c: (s, c, p, 0))
+    col = pl.BlockSpec((1, bc, 1), lambda s, p, c: (s, c, 0))
+    pkt = pl.BlockSpec((1, bp, bc), lambda s, p, c: (s, p, c))
+
+    in_specs = [tile]
+    operands = [pad_to(x, (S, Cp, Pp, F))]
+    if has_ef:
+        in_specs.append(tile)
+        operands.append(pad_to(ef.astype(x.dtype), (S, Cp, Pp, F)))
+    in_specs += [pkt, col]
+    operands += [pad_to(m.astype(jnp.float32), (S, Cp, Pp)).swapaxes(1, 2),
+                 pad_to(q.astype(jnp.float32), (S, Cp))[..., None]]
+    if per_coord:
+        in_specs.append(col)
+        operands.append(
+            pad_to(w_or_den.astype(jnp.float32), (S, Cp))[..., None])
+    else:
+        in_specs.append(pl.BlockSpec((1, 1, 1), lambda s, p, c: (s, 0, 0)))
+        operands.append(
+            jnp.asarray(w_or_den, jnp.float32).reshape(S, 1, 1))
+
+    out_specs = [pl.BlockSpec((1, bp, F), lambda s, p, c: (s, p, 0))]
+    out_shape = [jax.ShapeDtypeStruct((S, Pp, F), jnp.float32)]
+    if has_ef:
+        out_specs.append(tile)
+        out_shape.append(jax.ShapeDtypeStruct((S, Cp, Pp, F), x.dtype))
+    if want_ssq:
+        out_specs.append(pkt)
+        out_shape.append(jax.ShapeDtypeStruct((S, Pp, Cp), jnp.float32))
+
+    outs = list(pl.pallas_call(
+        functools.partial(_kernel, nc=nc, per_coord=per_coord,
+                          has_ef=has_ef, want_ssq=want_ssq, eps=eps,
+                          out_dtype=x.dtype),
+        grid=(S, gp, nc),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((bp, F), jnp.float32),   # agg accum
+                        pltpu.VMEM((bp,), jnp.float32)],    # den accum
+        interpret=interpret,
+    )(*operands))
+    agg = outs.pop(0)[:, :P]
+    ef_out = outs.pop(0)[:, :C, :P] if has_ef else None
+    ssq = outs.pop(0)[:, :P, :C].swapaxes(1, 2).sum(-1) if want_ssq \
+        else None
+    return agg, ef_out, ssq
+
+
 def uplink_fused_call(x, m, q, w_or_den, *, ef=None, want_ssq=False,
                       block_p: int | None = None, block_c: int | None = None,
                       interpret: bool | None = None, eps: float = DENOM_EPS,
                       per_coord: bool):
-    """Single-scenario megakernel call.
+    """Single-scenario megakernel call: the batched grid with S = 1.
 
     x: (C, P, F) packetised UNMASKED uploads, f32 or bf16 (the stream
     dtype); ef: matching (C, P, F) error-feedback tile or None;
@@ -167,114 +217,11 @@ def uplink_fused_call(x, m, q, w_or_den, *, ef=None, want_ssq=False,
     READY scalar denominator () — already ``max(sum w, DENOM_EPS)``.
 
     Returns (agg (P, F) f32, ef_out (C, P, F) stream-dtype | None,
-    ssq (C, P//block_p) f32 partials | None — sum axis 1 for ||.||^2).
+    ssq (C,) f32 masked squared norms | None).
     """
-    C, P, F = x.shape
-    bp, bc = pick_blocks(C, P, block_p, block_c)
-    gp, nc = P // bp, C // bc
-    interpret = resolve_interpret(interpret)
-    has_ef = ef is not None
-
-    in_specs = [pl.BlockSpec((bc, bp, F), lambda p, c: (c, p, 0))]
-    operands = [x]
-    if has_ef:
-        in_specs.append(pl.BlockSpec((bc, bp, F), lambda p, c: (c, p, 0)))
-        operands.append(ef.astype(x.dtype))
-    in_specs += [pl.BlockSpec((bc, bp), lambda p, c: (c, p)),
-                 pl.BlockSpec((bc, 1), lambda p, c: (c, 0))]
-    operands += [m.astype(jnp.float32), q.astype(jnp.float32)[:, None]]
-    if per_coord:
-        in_specs.append(pl.BlockSpec((bc, 1), lambda p, c: (c, 0)))
-        operands.append(w_or_den.astype(jnp.float32)[:, None])
-    else:
-        in_specs.append(pl.BlockSpec((1, 1), lambda p, c: (0, 0)))
-        operands.append(jnp.asarray(w_or_den, jnp.float32).reshape(1, 1))
-
-    out_specs = [pl.BlockSpec((bp, F), lambda p, c: (p, 0))]
-    out_shape = [jax.ShapeDtypeStruct((P, F), jnp.float32)]
-    if has_ef:
-        out_specs.append(pl.BlockSpec((bc, bp, F), lambda p, c: (c, p, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((C, P, F), x.dtype))
-    if want_ssq:
-        out_specs.append(pl.BlockSpec((bc, 1), lambda p, c: (c, p)))
-        out_shape.append(jax.ShapeDtypeStruct((C, gp), jnp.float32))
-
-    outs = pl.pallas_call(
-        functools.partial(_kernel_single, nc=nc, per_coord=per_coord,
-                          has_ef=has_ef, want_ssq=want_ssq, eps=eps,
-                          out_dtype=x.dtype),
-        grid=(gp, nc),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((bp, F), jnp.float32),   # agg accum
-                        pltpu.VMEM((bp,), jnp.float32)],    # den accum
-        interpret=interpret,
-    )(*operands)
-    outs = list(outs)
-    agg = outs.pop(0)
-    ef_out = outs.pop(0) if has_ef else None
-    ssq = outs.pop(0) if want_ssq else None
-    return agg, ef_out, ssq
-
-
-def uplink_fused_batched_call(x, m, q, w_or_den, *, ef=None, want_ssq=False,
-                              block_p: int | None = None,
-                              block_c: int | None = None,
-                              interpret: bool | None = None,
-                              eps: float = DENOM_EPS, per_coord: bool):
-    """Scenario-batched megakernel: a leading S grid axis over
-    (S, C, P, F) inputs, same body as ``uplink_fused_call`` — the sweep
-    engine's whole grid rides one kernel launch. Shapes follow the
-    single call with a leading S on every operand (``w_or_den`` is (S, C)
-    when ``per_coord``, else (S,) ready scalars)."""
-    S, C, P, F = x.shape
-    bp, bc = pick_blocks(C, P, block_p, block_c)
-    gp, nc = P // bp, C // bc
-    interpret = resolve_interpret(interpret)
-    has_ef = ef is not None
-
-    in_specs = [pl.BlockSpec((1, bc, bp, F), lambda s, p, c: (s, c, p, 0))]
-    operands = [x]
-    if has_ef:
-        in_specs.append(
-            pl.BlockSpec((1, bc, bp, F), lambda s, p, c: (s, c, p, 0)))
-        operands.append(ef.astype(x.dtype))
-    in_specs += [pl.BlockSpec((1, bc, bp), lambda s, p, c: (s, c, p)),
-                 pl.BlockSpec((1, bc, 1), lambda s, p, c: (s, c, 0))]
-    operands += [m.astype(jnp.float32), q.astype(jnp.float32)[..., None]]
-    if per_coord:
-        in_specs.append(pl.BlockSpec((1, bc, 1), lambda s, p, c: (s, c, 0)))
-        operands.append(w_or_den.astype(jnp.float32)[..., None])
-    else:
-        in_specs.append(pl.BlockSpec((1, 1, 1), lambda s, p, c: (s, 0, 0)))
-        operands.append(
-            jnp.asarray(w_or_den, jnp.float32).reshape(S, 1, 1))
-
-    out_specs = [pl.BlockSpec((1, bp, F), lambda s, p, c: (s, p, 0))]
-    out_shape = [jax.ShapeDtypeStruct((S, P, F), jnp.float32)]
-    if has_ef:
-        out_specs.append(
-            pl.BlockSpec((1, bc, bp, F), lambda s, p, c: (s, c, p, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((S, C, P, F), x.dtype))
-    if want_ssq:
-        out_specs.append(pl.BlockSpec((1, bc, 1), lambda s, p, c: (s, c, p)))
-        out_shape.append(jax.ShapeDtypeStruct((S, C, gp), jnp.float32))
-
-    outs = pl.pallas_call(
-        functools.partial(_kernel_batched, nc=nc, per_coord=per_coord,
-                          has_ef=has_ef, want_ssq=want_ssq, eps=eps,
-                          out_dtype=x.dtype),
-        grid=(S, gp, nc),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((bp, F), jnp.float32),
-                        pltpu.VMEM((bp,), jnp.float32)],
-        interpret=interpret,
-    )(*operands)
-    outs = list(outs)
-    agg = outs.pop(0)
-    ef_out = outs.pop(0) if has_ef else None
-    ssq = outs.pop(0) if want_ssq else None
-    return agg, ef_out, ssq
+    outs = uplink_fused_batched_call(
+        x[None], m[None], q[None], jnp.asarray(w_or_den)[None],
+        ef=None if ef is None else ef[None], want_ssq=want_ssq,
+        block_p=block_p, block_c=block_c, interpret=interpret, eps=eps,
+        per_coord=per_coord)
+    return tuple(None if o is None else o[0] for o in outs)

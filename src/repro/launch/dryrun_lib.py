@@ -181,8 +181,6 @@ def run_combo(arch: str, shape_name: str, mesh, *, mesh_name: str,
         res.compile_s = time.time() - t1
 
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):   # older jax: one dict per device
-            cost = cost[0] if cost else {}
         mem = compiled.memory_analysis()
         res.hlo_flops_raw = float(cost.get("flops", 0.0))
         res.hlo_bytes_raw = float(cost.get("bytes accessed", 0.0))

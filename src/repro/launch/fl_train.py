@@ -35,6 +35,7 @@ from repro.utils.events import EventWriter, RoundRecord, fingerprint_of
 from repro.models import transformer as tf
 from repro.optim.optimizers import (apply_updates, clip_by_global_norm,
                                     make_optimizer)
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.guards import assert_finite_tree
 from repro.utils.shardctx import shard
 
@@ -510,6 +511,7 @@ def main(argv=None):
                     help="write a jax.profiler trace (TensorBoard/"
                          "Perfetto) covering the training loop")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.telemetry != "off" and not args.events_out:
         ap.error("--telemetry scalars|full needs --events-out PATH")
     if args.events_out and args.telemetry == "off":

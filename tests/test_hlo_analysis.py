@@ -45,10 +45,7 @@ def test_real_scan_program_counts_iterations():
             return jax.lax.psum(c @ w, "i"), None
         return jax.lax.scan(body, x, None, length=5)[0]
 
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.6 keeps it under experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
     import numpy as np
     mesh = Mesh(np.array(jax.devices()[:1]), ("i",))

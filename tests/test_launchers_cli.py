@@ -6,7 +6,9 @@ import sys
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ENV = {**os.environ, "PYTHONPATH": "src"}
+# the launchers turn JAX's persistent compile cache on; tests keep it off
+ENV = {**os.environ, "PYTHONPATH": "src",
+       "JAX_ENABLE_COMPILATION_CACHE": "false"}
 
 
 def _run(args, timeout=420):

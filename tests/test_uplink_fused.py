@@ -234,11 +234,36 @@ def test_impl_resolution(monkeypatch):
         up_ops.resolved_impl("jnp")
 
 
-def test_pick_blocks_divisor_clamped():
-    bp, bc = pick_blocks(10, 18)            # MLP-ish: P=18, C=10
-    assert 18 % bp == 0 and 10 % bc == 0
-    bp, bc = pick_blocks(8, 16, block_p=7, block_c=5)
-    assert bp == 4 and bc == 4              # clamped to divisors
+def test_pick_blocks_divisor_clamped(monkeypatch):
+    """TPU blocks obey Mosaic's tiling rule (packet block P or a
+    multiple of 8, client block C or a multiple of 128); explicit
+    blocks are taken as given (the call pads to whole blocks); a
+    backend without a row is refused."""
+    assert pick_blocks(10, 18) == (18, 10)  # CPU: one block
+    assert pick_blocks(8, 16, block_p=7, block_c=5) == (7, 5)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert pick_blocks(10, 36) == (36, 10)  # paper MLP: one block
+    assert pick_blocks(64, 4096) == (16, 64)
+    bp, bc = pick_blocks(300, 5000)
+    assert bp % 8 == 0 and bc == 128
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(ValueError, match="no block sizes"):
+        pick_blocks(10, 36)
+
+
+@pytest.mark.parametrize("mode", DEBIAS_MODES)
+def test_kernel_padded_blocks_match_ref(case, mode):
+    """Blocks that do not divide (C, P) pad with zero clients and
+    undelivered packets, which change nothing: the EF update stays
+    bit-exact and the reductions match the reference."""
+    a1, e1, s1 = _call(case, mode, use_ef=True, want_ssq=True,
+                       impl="kernel", block_p=5, block_c=4)
+    a0, e0, s0 = _call(case, mode, use_ef=True, want_ssq=True,
+                       impl="ref")
+    np.testing.assert_allclose(np.asarray(a1), np.asarray(a0),
+                               rtol=2e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(s1), np.asarray(s0), rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(e1), np.asarray(e0))
 
 
 # ---------------------------------------------------------------------------
