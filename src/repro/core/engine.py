@@ -353,12 +353,17 @@ def _cached_jits(cfg, cohort: int):
     fp = tele_mod.REGISTRY.record_lookup("engine", key, hit=hit)
     if not hit:
         step = make_round_step(cfg, cohort)
+
+        # a named function, so the compiled module (and its runs in a
+        # profiler trace) is ``jit_round_block``
+        def round_block(ctx, state, ts):
+            return jax.lax.scan(lambda s, t: step(ctx, s, t), state, ts)
+
         single = tele_mod.TimedProgram(
-            jax.jit(step, donate_argnums=(1,)), "engine", fp)
-        block = tele_mod.TimedProgram(jax.jit(
-            lambda ctx, state, ts: jax.lax.scan(
-                lambda s, t: step(ctx, s, t), state, ts),
-            donate_argnums=(1,)), "engine", fp)
+            jax.jit(step, donate_argnums=(1,)), "engine", fp, "round")
+        block = tele_mod.TimedProgram(
+            jax.jit(round_block, donate_argnums=(1,)), "engine", fp,
+            "block")
         _STEP_CACHE[key] = (step, single, block)
     return _STEP_CACHE[key]
 
@@ -520,6 +525,34 @@ def validate_round_config(cfg) -> None:
             "no pressure signal)")
 
 
+# The round's stages, in the order the step runs them. Each names a
+# ``jax.named_scope``, so every op of the compiled program carries its
+# stage in its ``op_name`` metadata, and a profiler trace's device ops
+# can be summed by stage (bench/program_trace.py).
+STAGES = ("draws", "select", "gather", "downlink", "train", "faults",
+          "uplink", "server", "telemetry")
+
+
+class _StageScope:
+    """``stage("gather")`` closes the open stage's ``jax.named_scope``
+    and opens ``gather``'s, so each op the step traces falls in one
+    stage. Names only: the traced math is unchanged."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, name: str) -> None:
+        assert name in STAGES, name
+        self.close()
+        self._open = jax.named_scope(name)
+        self._open.__enter__()
+
+    def close(self) -> None:
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
 def make_round_step(cfg, cohort: int):
     """Build the round step ``step(ctx, state, t) -> (state, logs)``.
 
@@ -599,10 +632,18 @@ def make_round_step(cfg, cohort: int):
     down_stale = ns.down_fallback == "stale"
 
     def step(ctx: ScenarioCtx, state: EngineState, t):
+        stage = _StageScope()
+        try:
+            return staged_step(ctx, state, t, stage)
+        finally:
+            stage.close()
+
+    def staged_step(ctx, state, t, stage):
         dd = ctx.data
         N = dd.counts.shape[0]
         afl_len = min(64, dd.train_x.shape[1])
         params = state.params
+        stage("draws")
         old_vec, _ = ravel_pytree(params)
         # one threefry invocation covers the whole round: selection
         # gumbels, batch indices and the TRA packet draws (upload
@@ -650,6 +691,7 @@ def make_round_step(cfg, cohort: int):
                 u_de = u_all[off + C * P_dn:
                              off + 2 * C * P_dn].reshape(C, P_dn)
 
+        stage("select")
         # selection: weighted Gumbel-top-k over the eligibility mask.
         # Scores read the CARRY (previous round's channel/bandwidth/
         # score memory) — selection happens before this round's
@@ -676,6 +718,7 @@ def make_round_step(cfg, cohort: int):
                 bud_loss=state.bud_loss)
         ids = sel_mod.select_from_uniforms(u_sel, logits, ctx.eligible,
                                            C)
+        stage("gather")
         counts = dd.counts[ids]                              # (C,)
         idx = jnp.minimum((u_idx * counts[:, None, None]
                            ).astype(jnp.int32), counts[:, None, None] - 1)
@@ -688,6 +731,7 @@ def make_round_step(cfg, cohort: int):
         weights = w / w.sum()
         suff = ctx.sufficient[ids]
 
+        stage("downlink")
         # downlink broadcast: packetise the MODEL (D_model — scaffold's
         # control variate broadcast stays lossless, the documented
         # simplification), drop packets through the per-client downlink
@@ -730,6 +774,7 @@ def make_round_step(cfg, cohort: int):
                 + (1.0 - coord_dn) * stale_rows
             dn_frac = 1.0 - dmask.mean()
 
+        stage("train")
         # local training (vmapped cohort; per-client effective params
         # under downlink loss, the shared broadcast otherwise)
         if algo == "scaffold":
@@ -763,6 +808,7 @@ def make_round_step(cfg, cohort: int):
                     in_axes=(None, 0, 0))(params, X, Y)
             flat = flatten_clients(uploads, C)               # (C, D)
 
+        stage("faults")
         # client-level fault injection (repro/netsim/faults.py): what
         # the cohort actually UPLOADS — echo replays of the previous
         # genuine update, sign flips, NaN device failures. Drawn from a
@@ -777,6 +823,7 @@ def make_round_step(cfg, cohort: int):
                 fail_rate=ctx.f_fail, flip_rate=ctx.f_flip,
                 echo_rate=ctx.f_echo)
 
+        stage("uplink")
         # TRA uplink: EF re-inject, lossy-upload mask, per-mode debias
         # aggregation, the new EF memory rows and (q-FedAvg) the masked
         # squared norms — ONE pass over the (C, P, F) uploads through
@@ -1019,6 +1066,7 @@ def make_round_step(cfg, cohort: int):
         new_ef = state.ef_mem.at[ids].set(new_ef_rows) if ef \
             else state.ef_mem
 
+        stage("server")
         # async arrival buffer: pop entries due this round into the
         # aggregate, push this round's late uploads (core/async_agg.py)
         new_buf = state.buf
@@ -1165,6 +1213,7 @@ def make_round_step(cfg, cohort: int):
             bud_level_new = state.bud_level.at[ids].set(lv)
             bud_loss_new = state.bud_loss.at[ids].set(ema_new)
 
+        stage("telemetry")
         logs = {"loss": aux["loss0"].mean(), "ids": ids}
         if use_faults:
             # per-cohort-slot quarantined-packet counts — the
@@ -1326,6 +1375,15 @@ class RoundScanEngine:
                   ) -> Tuple[EngineState, Dict[str, np.ndarray]]:
         """Scan rounds [t0, t0+k) in one device program; flush logs to
         host. Returns (state, {"loss": (k,), "ids": (k, C)})."""
-        ts = jnp.arange(t0, t0 + k, dtype=jnp.int32)
-        state, logs = self._block(self.ctx, state, ts)
-        return state, {k_: np.asarray(v) for k_, v in logs.items()}
+        with tele_mod.Span("fl.block"):
+            with tele_mod.Span("fl.block.args"):
+                ts = jnp.arange(t0, t0 + k, dtype=jnp.int32)
+            state, logs = self._block(self.ctx, state, ts)
+            # no wait of its own: ``np.asarray`` waits for the program,
+            # and a wait before it starts the copies only once the host
+            # has seen the program end (a round trip, about 0.13 ms a
+            # block on a TPU v5e)
+            with tele_mod.Span("fl.block.copy"):
+                out = {k_: np.asarray(v) for k_, v in logs.items()}
+            self._block.count(d2h=sum(v.nbytes for v in out.values()))
+        return state, out

@@ -182,7 +182,11 @@ class FederatedServer:
                                       packet_loss=self.nets.packet_loss)
         self._state = self.engine.init_state(
             mlp_init(jax.random.PRNGKey(cfg.seed)))
-        self._eval_fn = jax.jit(jax.vmap(mlp_accuracy, in_axes=(None, 0, 0, 0)))
+        self._eval_fn = tele_mod.TimedProgram(
+            jax.jit(jax.vmap(mlp_accuracy, in_axes=(None, 0, 0, 0))),
+            "eval", tele_mod.REGISTRY.record_lookup(
+                "eval", ("vmap(mlp_accuracy)", self.eval_X.shape),
+                hit=False), "eval")
         self.history: List[RoundLog] = []
 
     # -- device-resident state, host views ----------------------------------
@@ -307,11 +311,21 @@ class FederatedServer:
     # -- evaluation ----------------------------------------------------------
     def evaluate(self, params=None) -> FairnessReport:
         p = self.params if params is None else params
-        acc, correct, n = self._eval_fn(p, jnp.asarray(self.eval_X),
-                                        jnp.asarray(self.eval_Y),
-                                        jnp.asarray(self.eval_W))
-        return fairness_report(np.asarray(acc), np.asarray(n),
-                               np.asarray(correct))
+        with tele_mod.Span("fl.eval"):
+            with tele_mod.Span("fl.eval.upload"):
+                X, Y, W = (jnp.asarray(self.eval_X),
+                           jnp.asarray(self.eval_Y),
+                           jnp.asarray(self.eval_W))
+            acc, correct, n = self._eval_fn(p, X, Y, W)
+            with tele_mod.Span("fl.eval.copy"):
+                acc, correct, n = (np.asarray(acc), np.asarray(correct),
+                                   np.asarray(n))
+            self._eval_fn.count(
+                h2d=self.eval_X.nbytes + self.eval_Y.nbytes
+                + self.eval_W.nbytes,
+                d2h=acc.nbytes + correct.nbytes + n.nbytes)
+            with tele_mod.Span("fl.eval.report"):
+                return fairness_report(acc, n, correct)
 
     def evaluate_personalized(self) -> FairnessReport:
         """Adapt the global model per client, then evaluate (pFedMe 'P' /

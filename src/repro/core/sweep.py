@@ -398,12 +398,15 @@ class SweepEngine:
                                    rec_backoff=0, bud_budget=0,
                                    bud_ema=0, bud_div=0)
             vstep = jax.vmap(step, in_axes=(ctx_axes, 0, None))
+
+            # named, so the compiled module is ``jit_sweep_block``
+            def sweep_block(ctx, state, ts):
+                return jax.lax.scan(lambda s, t: vstep(ctx, s, t), state,
+                                    ts)
+
             _SWEEP_CACHE[cache_key] = (step, tele_mod.TimedProgram(
-                jax.jit(
-                    lambda ctx, state, ts: jax.lax.scan(
-                        lambda s, t: vstep(ctx, s, t), state, ts),
-                    donate_argnums=(1,)),
-                "sweep", fp))
+                jax.jit(sweep_block, donate_argnums=(1,)), "sweep", fp,
+                "sweep"))
         self._step, self._block = _SWEEP_CACHE[cache_key]
 
     @classmethod

@@ -38,12 +38,19 @@ DEVICE and flushed with the block. This module is that layer:
     stream that ``tools/flstat.py`` renders.
 
   * ``REGISTRY`` / ``TimedProgram`` — the host-side program-timing
-    layer wrapping the engine/sweep step caches: every cache lookup
-    logs the ``static_signature`` fingerprint (hit or insert), every
-    dispatch records wall time split compile vs execute, and a
+    layer wrapping the engine/sweep/eval program caches: every cache
+    lookup logs the ``static_signature`` fingerprint (hit or insert),
+    every call records host time split compile vs dispatch (the
+    enqueue: JAX returns before the device runs the program) and the
+    bytes its call site copies to and from the device, and a
     fingerprint collision between two DIFFERENT static keys raises
     immediately — "one program per grid" becomes a measured, logged
     invariant instead of a benchmark-only assertion.
+
+  * ``SPANS`` / ``Span`` — the program's host spans: ``fl.*`` names
+    that a profiler trace shows on the same clock as the device, so an
+    idle gap on the device can be put down to what the host was doing
+    in it; ``REGISTRY`` keeps each span's count and host seconds.
 
 Telemetry reads signals the round already computes (masks, arrival
 weights, quarantine counts, EF rows); it never changes the training
@@ -59,6 +66,7 @@ from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.utils.events import RoundRecord, fingerprint_of
 
@@ -289,14 +297,19 @@ class ProgramStat:
     x cohort/shape family), keyed by the fingerprint of the cache key
     the engine/sweep caches use."""
     fingerprint: str
-    kind: str                   # "engine" | "sweep"
+    kind: str                   # "engine" | "sweep" | "launch" | "eval"
     key_repr: str               # full static cache key (diagnosable!)
     hits: int = 0               # cache lookups that found the program
     misses: int = 0             # cache lookups that built it
     calls: int = 0              # dispatches through the timing wrapper
     compiles: int = 0           # dispatches that traced+compiled
     compile_seconds: float = 0.0  # wall time of compiling dispatches
-    exec_seconds: float = 0.0     # wall time of cached dispatches
+    # host time of the cached calls: argument flattening, donation and
+    # the enqueue — not the device's execution, which JAX does not wait
+    # for before it returns
+    dispatch_seconds: float = 0.0
+    h2d_bytes: int = 0          # host->device bytes its call sites sent
+    d2h_bytes: int = 0          # device->host bytes they copied back
 
     def as_dict(self) -> Dict[str, Any]:
         d = dataclasses.asdict(self)
@@ -320,9 +333,14 @@ class ProgramRegistry:
 
     def __init__(self):
         self._stats: Dict[Any, ProgramStat] = {}
+        # span name -> [count, host seconds] (``Span``)
+        self._spans: Dict[str, List[float]] = {}
+        self.compiles = 0           # compiling dispatches, all programs
 
     def reset(self) -> None:
         self._stats.clear()
+        self._spans.clear()
+        self.compiles = 0
 
     def record_lookup(self, kind: str, key: Any, *, hit: bool) -> str:
         fp = fingerprint_of(key)
@@ -347,21 +365,44 @@ class ProgramRegistry:
                      "hit" if hit else "insert", fp)
         return fp
 
-    def record_call(self, kind: str, fp: str, seconds: float,
-                    compiled: bool) -> None:
+    def _stat(self, kind: str, fp: str) -> ProgramStat:
         st = self._stats.get((kind, fp))
         if st is None:  # timing without a lookup (tests driving fns)
             st = ProgramStat(fingerprint=fp, kind=kind, key_repr="")
             self._stats[(kind, fp)] = st
+        return st
+
+    def record_call(self, kind: str, fp: str, seconds: float,
+                    compiled: bool) -> None:
+        st = self._stat(kind, fp)
         st.calls += 1
         if compiled:
             st.compiles += 1
             st.compile_seconds += seconds
+            self.compiles += 1
         else:
-            st.exec_seconds += seconds
+            st.dispatch_seconds += seconds
+
+    def record_bytes(self, kind: str, fp: str, *, h2d: int = 0,
+                     d2h: int = 0) -> None:
+        st = self._stat(kind, fp)
+        st.h2d_bytes += h2d
+        st.d2h_bytes += d2h
+
+    def record_span(self, name: str, seconds: float) -> None:
+        c = self._spans.setdefault(name, [0, 0.0])
+        c[0] += 1
+        c[1] += seconds
 
     def stats(self) -> List[Dict[str, Any]]:
         return [st.as_dict() for st in self._stats.values()]
+
+    def span_stats(self) -> Dict[str, Dict[str, float]]:
+        """Per span name: how many closed (``count``) and their host
+        time (``seconds``), less those in which a program compiled or
+        that closed while a profiler trace recorded (``Span``)."""
+        return {name: {"count": c[0], "seconds": c[1]}
+                for name, c in self._spans.items()}
 
     def get(self, kind: str, fp: str) -> Optional[ProgramStat]:
         return self._stats.get((kind, fp))
@@ -393,29 +434,102 @@ REGISTRY = ProgramRegistry()
 class TimedProgram:
     """Transparent timing wrapper around one cached jitted callable.
 
-    Every call is wall-clocked and recorded against the program's
-    signature fingerprint; a call that grew the jit's compiled-program
-    count is booked as a compile (trace+lower+compile included),
-    everything else as execution. Attribute access falls through to the
-    wrapped function, so ``_cache_size()`` probes and donation checks
-    keep working on the wrapped object.
+    Every call is wall-clocked inside the host span
+    ``fl.<span>.dispatch`` and recorded against the program's signature
+    fingerprint; a call that grew the jit's compiled-program count is
+    booked as a compile (trace+lower+compile included), everything else
+    as dispatch. JAX returns once the program is enqueued, so dispatch
+    time is host time, not the device's. ``count`` books the bytes a
+    call site copies to and from the device. Attribute access falls
+    through to the wrapped function, so ``_cache_size()`` probes and
+    donation checks keep working on the wrapped object.
     """
 
-    def __init__(self, fn, kind: str, fingerprint: str):
+    def __init__(self, fn, kind: str, fingerprint: str, span: str):
         self._fn = fn
         self._kind = kind
         self._fp = fingerprint
+        self._span = f"fl.{span}.dispatch"
+        if self._span not in SPANS:
+            raise ValueError(f"no host span {self._span!r} in SPANS")
 
     def __call__(self, *args, **kwargs):
-        probe = getattr(self._fn, "_cache_size", None)
-        n0 = probe() if probe is not None else -1
-        t0 = time.perf_counter()
-        out = self._fn(*args, **kwargs)
-        dt = time.perf_counter() - t0
-        n1 = probe() if probe is not None else -1
-        REGISTRY.record_call(self._kind, self._fp, dt,
-                             compiled=n1 > n0 >= 0)
+        with Span(self._span):
+            probe = getattr(self._fn, "_cache_size", None)
+            n0 = probe() if probe is not None else -1
+            t0 = time.perf_counter()
+            out = self._fn(*args, **kwargs)
+            dt = time.perf_counter() - t0
+            n1 = probe() if probe is not None else -1
+            REGISTRY.record_call(self._kind, self._fp, dt,
+                                 compiled=n1 > n0 >= 0)
         return out
+
+    def count(self, *, h2d: int = 0, d2h: int = 0) -> None:
+        """Book ``h2d`` bytes sent to the device and ``d2h`` bytes
+        copied back for this program."""
+        REGISTRY.record_bytes(self._kind, self._fp, h2d=h2d, d2h=d2h)
 
     def __getattr__(self, name):
         return getattr(self._fn, name)
+
+
+# ---------------------------------------------------------------------------
+# host spans: the program's own names on the profiler's clock
+# ---------------------------------------------------------------------------
+# Every host span the program opens. Children nest in their parent
+# (``fl.block`` holds ``fl.block.args`` ... ``fl.block.copy``). The
+# names start with ``fl.`` so that they never collide with a caller's
+# own spans.
+SPANS = {
+    "fl.block": "RoundScanEngine.run_block: one block of rounds, its "
+                "logs on the host",
+    "fl.block.args": "the block's round indices, a device array",
+    "fl.block.dispatch": "the block program's call: argument "
+                         "flattening, state donation, enqueue",
+    "fl.block.copy": "the wait for the block program and the logs' "
+                     "device-to-host copy",
+    "fl.round.dispatch": "the single-round program's call",
+    "fl.sweep.dispatch": "the sweep block program's call",
+    "fl.launch.dispatch": "a launch-route step's call",
+    "fl.eval": "FederatedServer.evaluate: one fairness eval",
+    "fl.eval.upload": "the eval set's host-to-device copy",
+    "fl.eval.dispatch": "the eval program's call",
+    "fl.eval.copy": "the wait for the eval program and the copy of "
+                    "its outputs",
+    "fl.eval.report": "fairness_report on the host",
+}
+
+
+class Span:
+    """``with Span("fl.block.copy"): ...`` — a host span of the program.
+
+    It writes a ``jax.profiler.TraceAnnotation`` under the span's plain
+    name, which a profiler trace shows on the device's clock, and books
+    the span's host seconds in ``REGISTRY.span_stats()``, which costs
+    two clock reads. The counts read the steady state with the profiler
+    off: a span in which a registered program compiled, or that closes
+    while a trace records (the profiler slows the host), is not booked.
+    With the profiler off the annotation costs an enter and an exit.
+    """
+    __slots__ = ("name", "_ann", "_c0", "_t0")
+
+    def __init__(self, name: str):
+        if name not in SPANS:
+            raise ValueError(f"no host span {name!r} in SPANS")
+        self.name = name
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self._c0 = REGISTRY.compiles
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        if REGISTRY.compiles == self._c0 \
+                and not TraceAnnotation.is_enabled():
+            REGISTRY.record_span(self.name, dt)
+        return False

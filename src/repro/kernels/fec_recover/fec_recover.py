@@ -66,6 +66,7 @@ def fec_recover_call(mask, parity, *, group: int,
         in_specs=[mtile, pl.BlockSpec((bg, bc), lambda c, g: (g, c))],
         out_specs=mtile,
         out_shape=jax.ShapeDtypeStruct((Gp, group, Cp), jnp.float32),
+        name="fec_recover",
         interpret=interpret,
     )(m, par)
     return out[:gn].reshape(p_pad, Cp)[:, :C].T

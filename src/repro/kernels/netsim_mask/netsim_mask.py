@@ -101,6 +101,7 @@ def netsim_mask_call(u_t, u_e, s0, p_gb, p_bg, h_g, h_b, *,
         out_shape=[jax.ShapeDtypeStruct((Pp, Cp), jnp.float32),
                    jax.ShapeDtypeStruct((1, Cp), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((1, bc), jnp.float32)],
+        name="netsim_mask",
         interpret=interpret,
     )(packets(u_t, 2.0), packets(u_e, 0.0), clients(s0, jnp.int32),
       clients(p_gb), clients(p_bg), clients(h_g), clients(h_b))

@@ -208,6 +208,7 @@ def robust_agg_batched_call(x, m, q, w_or_den, screen, trim_gate, *,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        name="robust_agg",
         interpret=interpret,
     )(*operands))
     agg = outs.pop(0)[:, :P]

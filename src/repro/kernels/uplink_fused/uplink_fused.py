@@ -194,6 +194,7 @@ def uplink_fused_batched_call(x, m, q, w_or_den, *, ef=None,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bp, F), jnp.float32),   # agg accum
                         pltpu.VMEM((bp,), jnp.float32)],    # den accum
+        name="uplink_fused",
         interpret=interpret,
     )(*operands))
     agg = outs.pop(0)[:, :P]

@@ -397,7 +397,7 @@ def _timed(fn, route: str, args):
     fp = tele_mod.REGISTRY.record_lookup(
         "launch", (args.arch, route, args.clients, args.debias,
                    args.server_mode), hit=False)
-    return tele_mod.TimedProgram(fn, "launch", fp)
+    return tele_mod.TimedProgram(fn, "launch", fp, "launch")
 
 
 # Selection policies the host-driven launch loop supports. netsim_state

@@ -175,9 +175,10 @@ class EventWriter:
         self.write("round", rec.to_json())
 
     def write_program_stats(self, stats: List[Dict[str, Any]]) -> None:
-        """Flush the program-timing registry (compile/exec/cache
-        counters keyed by static-signature fingerprint). The registry's
-        own ``kind`` field ("engine"/"sweep") is renamed ``cache`` so it
+        """Flush the program-timing registry (compile/dispatch/cache
+        and byte counters keyed by static-signature fingerprint). The
+        registry's own ``kind`` field ("engine"/"sweep"/"launch"/"eval")
+        is renamed ``cache`` so it
         cannot clobber the event's kind tag."""
         for s in stats:
             s = dict(s)

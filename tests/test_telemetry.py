@@ -323,7 +323,7 @@ def test_timed_program_records_compile_and_exec():
     reg_before = tele_mod.REGISTRY.get("engine", "deadbeef")
     assert reg_before is None or reg_before.calls == 0
     fn = jax.jit(lambda x: x * 2)
-    timed = tele_mod.TimedProgram(fn, "engine", "deadbeef")
+    timed = tele_mod.TimedProgram(fn, "engine", "deadbeef", "block")
     timed(jnp.ones(4))          # compiles
     timed(jnp.ones(4))          # cached
     st = tele_mod.REGISTRY.get("engine", "deadbeef")
@@ -332,6 +332,73 @@ def test_timed_program_records_compile_and_exec():
     assert st.compile_seconds > 0
     # attribute fall-through keeps jit probes working on the wrapper
     assert timed._cache_size() >= 1
+
+
+def test_timed_program_books_dispatch_bytes_and_spans(tmp_path):
+    reg = ProgramRegistry()
+    real = tele_mod.REGISTRY
+    tele_mod.REGISTRY = reg
+    try:
+        timed = tele_mod.TimedProgram(jax.jit(lambda x: x + 1), "eval",
+                                      "cafe", "eval")
+        timed(jnp.ones(3))          # compiles: not a dispatch
+        for _ in range(3):
+            timed(jnp.ones(3))
+        timed.count(h2d=12, d2h=4)
+        timed.count(d2h=4)
+        with tele_mod.Span("fl.eval.copy"):
+            pass
+    finally:
+        tele_mod.REGISTRY = real
+    st = reg.get("eval", "cafe")
+    assert (st.calls, st.compiles) == (4, 1)
+    assert st.dispatch_seconds > 0 and st.compile_seconds > 0
+    assert (st.h2d_bytes, st.d2h_bytes) == (12, 8)
+    assert not hasattr(st, "exec_seconds")
+    spans = reg.span_stats()
+    # the compiling call's span is left out of the host-time counts
+    assert spans["fl.eval.dispatch"]["count"] == 3
+    assert spans["fl.eval.dispatch"]["seconds"] > 0
+    assert spans["fl.eval.copy"]["count"] == 1
+    with pytest.raises(ValueError):
+        tele_mod.Span("eval.copy")
+    # a span that closes while a profiler trace records is not booked
+    tele_mod.REGISTRY = reg
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tele_mod.Span("fl.eval.copy"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        tele_mod.REGISTRY = real
+    assert reg.span_stats()["fl.eval.copy"]["count"] == 1
+    with pytest.raises(ValueError):
+        tele_mod.TimedProgram(timed, "eval", "cafe", "nope")
+
+
+def test_server_counts_block_and_eval_bytes(data, nets):
+    reg = ProgramRegistry()
+    real = tele_mod.REGISTRY
+    tele_mod.REGISTRY = reg
+    try:
+        srv = FederatedServer(_cfg(rounds=2, eval_every=2), data, nets)
+        srv._state, logs = srv.engine.run_block(srv._state, 0, 2)
+        srv.evaluate()
+    finally:
+        tele_mod.REGISTRY = real
+    eng = next(st for st in reg._stats.values() if st.kind == "engine")
+    ev = next(st for st in reg._stats.values() if st.kind == "eval")
+    assert eng.d2h_bytes == sum(v.nbytes for v in logs.values()) > 0
+    assert eng.h2d_bytes == 0
+    assert ev.calls == 1 and ev.misses == 1
+    assert ev.h2d_bytes == (srv.eval_X.nbytes + srv.eval_Y.nbytes
+                            + srv.eval_W.nbytes)
+    assert ev.d2h_bytes == 3 * N_CLIENTS * 4
+    assert set(reg.span_stats()) >= {
+        "fl.block.args", "fl.block.copy",
+        "fl.eval.upload", "fl.eval.copy", "fl.eval.report"}
 
 
 # ---------------------------------------------------------------------------
@@ -426,3 +493,28 @@ def test_flstat_parses_real_stream(data, nets, tmp_path):
     with redirect_stdout(buf):
         assert flstat.main([path, "--rounds"]) == 0
         assert flstat.main([path, "--programs"]) == 0
+
+
+def test_flstat_programs_renders_dispatch_and_bytes(tmp_path):
+    import importlib
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "tools"))
+    flstat = importlib.import_module("flstat")
+    path = tmp_path / "ev.jsonl"
+    new = {"kind": "program", "cache": "eval", "fingerprint": "f1",
+           "calls": 3, "dispatch_seconds": 0.25, "h2d_bytes": 4800,
+           "d2h_bytes": 120}
+    # a stream written before the rename still renders its time
+    old = {"kind": "program", "cache": "engine", "fingerprint": "f0",
+           "calls": 2, "exec_seconds": 0.5}
+    path.write_text(json.dumps({"kind": "header"}) + "\n"
+                    + json.dumps(new) + "\n" + json.dumps(old) + "\n")
+    buf = io.StringIO()
+    with redirect_stdout(buf):
+        assert flstat.main([str(path), "--programs"]) == 0
+    head, row_new, row_old = buf.getvalue().splitlines()
+    assert "dispatch_s" in head and "exec_s" not in head
+    assert "h2d_bytes" in head and "d2h_bytes" in head
+    assert row_new.split()[-3:] == ["0.250", "4800", "120"]
+    assert row_old.split()[-3:] == ["0.500", "0", "0"]

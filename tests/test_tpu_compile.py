@@ -123,3 +123,65 @@ def test_fec_recover_compiles(one_chip, tpu_rows, C, P, batched):
     _assert_compiles(jax.vmap(fn) if batched else fn, one_chip,
                      [((C, gn * GROUP), f32), ((C, gn), f32)],
                      lead=(S,) if batched else ())
+
+
+def _bench_tracing():
+    """``bench/tracing.py`` by path: the benchmark's kernel map."""
+    import importlib.util
+    import sys
+    from pathlib import Path
+    path = Path(__file__).resolve().parents[1] / "bench" / "tracing.py"
+    spec = importlib.util.spec_from_file_location("bench_tracing", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod    # its dataclasses look it up
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _kernel_case(kernel, C, P):
+    """(fn, shapes) of one engine kernel's call at (C, P)."""
+    gn = -(-P // GROUP)
+    return {
+        "uplink_fused": (
+            lambda x, m, q, den, ef: uplink_fused_call(
+                x, m, q, den, ef=ef, want_ssq=True, per_coord=False,
+                interpret=False),
+            [((C, P, F), f32), ((C, P), f32), ((C,), f32), ((), f32),
+             ((C, P, F), f32)]),
+        "robust_agg": (
+            lambda x, m, q, den, scr, trg, ef, g, wpos: robust_agg_call(
+                x, m, q, den, scr, trg, ef=ef, g=g, w_pos=wpos, trim_k=1,
+                per_coord=False, interpret=False),
+            [((C, P, F), f32), ((C, P), f32), ((C,), f32), ((), f32),
+             ((), f32), ((), f32), ((C, P, F), f32), ((C,), f32),
+             ((C,), f32)]),
+        "netsim_mask": (
+            lambda *a: netsim_mask_call(*a, interpret=False),
+            [((C, P), f32), ((C, P), f32), ((C,), i32), ((C,), f32),
+             ((C,), f32), ((C,), f32), ((C,), f32)]),
+        "fec_recover": (
+            lambda m, par: fec_recover_call(m, par, group=GROUP,
+                                            interpret=False),
+            [((C, gn * GROUP), f32), ((C, gn), f32)]),
+    }[kernel]
+
+
+@pytest.mark.parametrize("kernel", ["uplink_fused", "robust_agg",
+                                    "netsim_mask", "fec_recover"])
+def test_kernel_custom_calls_carry_their_names(one_chip, tpu_rows,
+                                               kernel):
+    """Each kernel's ``pallas_call`` ``name=`` names its custom call in
+    the compiled HLO (and so its op events in a profiler trace), and
+    the benchmark's stack-frame map agrees."""
+    fn, shapes = _kernel_case(kernel, *SIZES[0])
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line.split("=", 1)[0].strip().lstrip("%")
+             for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls, hlo[:500]
+    assert all(c.split(".")[0] == kernel for c in calls), calls
+    by_frames = _bench_tracing().kernel_instructions(hlo)
+    assert {c: by_frames.get(c) for c in calls} == {c: kernel
+                                                    for c in calls}

@@ -190,13 +190,17 @@ def print_programs(programs: List[dict]) -> None:
         print("no program events in stream (writer closed early?)")
         return
     print(f"{'cache':8} {'fingerprint':17} {'hit':>4} {'miss':>4} "
-          f"{'calls':>5} {'compiles':>8} {'compile_s':>9} {'exec_s':>8}")
+          f"{'calls':>5} {'compiles':>8} {'compile_s':>9} "
+          f"{'dispatch_s':>10} {'h2d_bytes':>11} {'d2h_bytes':>11}")
     for p in programs:
+        # streams written before the rename carry ``exec_seconds``
+        dispatch = p.get("dispatch_seconds", p.get("exec_seconds", 0.0))
         print(f"{p.get('cache', '?'):8} {p.get('fingerprint', '?'):17} "
               f"{p.get('hits', 0):4d} {p.get('misses', 0):4d} "
               f"{p.get('calls', 0):5d} {p.get('compiles', 0):8d} "
               f"{p.get('compile_seconds', 0.0):9.3f} "
-              f"{p.get('exec_seconds', 0.0):8.3f}")
+              f"{dispatch:10.3f} {p.get('h2d_bytes', 0):11d} "
+              f"{p.get('d2h_bytes', 0):11d}")
 
 
 def main(argv=None) -> int:
@@ -206,7 +210,8 @@ def main(argv=None) -> int:
     ap.add_argument("--rounds", action="store_true",
                     help="per-round table instead of the summary")
     ap.add_argument("--programs", action="store_true",
-                    help="program-timing ledger (compile/exec/cache)")
+                    help="program-timing ledger (compile/dispatch/"
+                    "cache/bytes)")
     ap.add_argument("--scenario", type=int, default=None,
                     help="restrict --rounds to one scenario")
     ap.add_argument("--json", action="store_true",
