@@ -310,6 +310,8 @@ class ProgramStat:
     dispatch_seconds: float = 0.0
     h2d_bytes: int = 0          # host->device bytes its call sites sent
     d2h_bytes: int = 0          # device->host bytes they copied back
+    d2h_fetches: int = 0        # device->host fetches (one wait each)
+    d2h_arrays: int = 0         # arrays those fetches copied
 
     def as_dict(self) -> Dict[str, Any]:
         d = dataclasses.asdict(self)
@@ -384,10 +386,13 @@ class ProgramRegistry:
             st.dispatch_seconds += seconds
 
     def record_bytes(self, kind: str, fp: str, *, h2d: int = 0,
-                     d2h: int = 0) -> None:
+                     d2h: int = 0, arrays: int = 0) -> None:
         st = self._stat(kind, fp)
         st.h2d_bytes += h2d
         st.d2h_bytes += d2h
+        if arrays:
+            st.d2h_fetches += 1
+            st.d2h_arrays += arrays
 
     def record_span(self, name: str, seconds: float) -> None:
         c = self._spans.setdefault(name, [0, 0.0])
@@ -440,9 +445,10 @@ class TimedProgram:
     booked as a compile (trace+lower+compile included), everything else
     as dispatch. JAX returns once the program is enqueued, so dispatch
     time is host time, not the device's. ``count`` books the bytes a
-    call site copies to and from the device. Attribute access falls
-    through to the wrapped function, so ``_cache_size()`` probes and
-    donation checks keep working on the wrapped object.
+    call site copies to and from the device, and its fetches back.
+    Attribute access falls through to the wrapped function, so
+    ``_cache_size()`` probes and donation checks keep working on the
+    wrapped object.
     """
 
     def __init__(self, fn, kind: str, fingerprint: str, span: str):
@@ -465,10 +471,13 @@ class TimedProgram:
                                  compiled=n1 > n0 >= 0)
         return out
 
-    def count(self, *, h2d: int = 0, d2h: int = 0) -> None:
+    def count(self, *, h2d: int = 0, d2h: int = 0,
+              arrays: int = 0) -> None:
         """Book ``h2d`` bytes sent to the device and ``d2h`` bytes
-        copied back for this program."""
-        REGISTRY.record_bytes(self._kind, self._fp, h2d=h2d, d2h=d2h)
+        copied back for this program; ``arrays`` > 0 books the copy
+        back as one fetch of that many arrays."""
+        REGISTRY.record_bytes(self._kind, self._fp, h2d=h2d, d2h=d2h,
+                              arrays=arrays)
 
     def __getattr__(self, name):
         return getattr(self._fn, name)

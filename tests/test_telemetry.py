@@ -401,6 +401,78 @@ def test_server_counts_block_and_eval_bytes(data, nets):
         "fl.eval.upload", "fl.eval.copy", "fl.eval.report"}
 
 
+# the default FedAvg logs two arrays a block; faults, screening and the
+# deadline (as in the benchmark's defended cell) four
+LOG_KEYS = [(dict(tra_on=False), {"loss", "ids"}),
+            (dict(faults_on=True), {"loss", "ids", "quarantine",
+                                    "arrival"})]
+
+
+class _RecordedLog:
+    """A block log that records when its copy starts and converts."""
+
+    def __init__(self, key, arr, calls):
+        self.key, self.arr, self.calls = key, arr, calls
+
+    def copy_to_host_async(self):
+        self.calls.append(("start", self.key))
+        self.arr.copy_to_host_async()
+
+    def __array__(self, dtype=None, copy=None):
+        self.calls.append(("convert", self.key))
+        return np.asarray(self.arr, dtype)
+
+
+@pytest.mark.parametrize("kw,keys", LOG_KEYS)
+def test_run_block_starts_every_log_copy_before_converting(kw, keys,
+                                                           data, nets):
+    srv = FederatedServer(_cfg(rounds=2, **kw), data, nets)
+    eng, real, calls, seen = srv.engine, srv.engine._block, [], {}
+
+    class Block:
+        def __call__(self, *args):
+            state, logs = real(*args)
+            seen.update(logs)
+            return state, {k: _RecordedLog(k, v, calls)
+                           for k, v in logs.items()}
+
+        def count(self, **kw):
+            real.count(**kw)
+
+    eng._block = Block()
+    _, out = eng.run_block(srv._state, 0, 2)
+    assert set(out) == set(seen) == keys
+    starts = [i for i, (op, _) in enumerate(calls) if op == "start"]
+    converts = [i for i, (op, _) in enumerate(calls) if op == "convert"]
+    assert sorted(k for op, k in calls if op == "start") == sorted(keys)
+    assert len(converts) == len(keys) and max(starts) < min(converts)
+    for k, v in seen.items():
+        ref = np.asarray(v)
+        assert isinstance(out[k], np.ndarray)
+        assert (out[k].dtype, out[k].shape) == (ref.dtype, ref.shape)
+        assert out[k].tobytes() == ref.tobytes()
+
+
+@pytest.mark.parametrize("kw,keys", LOG_KEYS)
+def test_run_block_counts_one_fetch_a_block(kw, keys, data, nets):
+    reg = ProgramRegistry()
+    real = tele_mod.REGISTRY
+    tele_mod.REGISTRY = reg
+    try:
+        srv = FederatedServer(_cfg(rounds=4, **kw), data, nets)
+        srv._state, a = srv.engine.run_block(srv._state, 0, 2)
+        srv._state, b = srv.engine.run_block(srv._state, 2, 2)
+    finally:
+        tele_mod.REGISTRY = real
+    assert set(a) == set(b) == keys
+    eng = next(st for st in reg._stats.values() if st.kind == "engine")
+    assert (eng.d2h_fetches, eng.d2h_arrays) == (2, 2 * len(keys))
+    assert eng.d2h_bytes == sum(v.nbytes for v in [*a.values(),
+                                                    *b.values()])
+    rec = eng.as_dict()
+    assert (rec["d2h_fetches"], rec["d2h_arrays"]) == (2, 2 * len(keys))
+
+
 # ---------------------------------------------------------------------------
 # event stream + flstat
 # ---------------------------------------------------------------------------
