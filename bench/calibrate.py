@@ -10,7 +10,7 @@ plain reference (the lower readings). For each of ``--control-seeds``
 it prints the same numbers for the reference put in the program's
 place: computed with float8 (e4m3) matrix-product operands, one step
 below the bfloat16 operands the configuration runs (the control), and
-with each planted fault (``bench/reference/fl_round.FAULTS``). One JSON
+with each fault the reference file can plant (its ``FAULTS``). One JSON
 line per reading.
 """
 from __future__ import annotations
@@ -40,8 +40,7 @@ def main(argv=None) -> int:
                                                     / "jax")
     import jax
     from repro.utils.compile_cache import enable_compile_cache
-    from bench import compare, harness
-    from bench.reference import fl_round
+    from bench import compare, harness, models
 
     spec = harness.cell_spec(args.workload)
     try:
@@ -53,6 +52,7 @@ def main(argv=None) -> int:
     enable_compile_cache()
     k, n_blocks = spec["traffic"]["block_rounds"], harness.SETUP_BLOCKS
     reference = harness.reference
+    faults = models.load_reference(spec["config"]["reference"]).FAULTS
 
     def emit(kind, seed, numbers, t0):
         print(json.dumps({"workload": args.workload, "kind": kind,
@@ -83,7 +83,7 @@ def main(argv=None) -> int:
         clean = compare.reference_blocks(reference(run), run.params0, k,
                                          n_blocks)
         for kind, kw in [("control", {"dot_dtype": CONTROL_DTYPE})] + [
-                (f"fault:{f}", {"fault": f}) for f in fl_round.FAULTS]:
+                (f"fault:{f}", {"fault": f}) for f in faults]:
             t1 = time.time()
             other = compare.reference_blocks(reference(run, **kw),
                                              run.params0, k, n_blocks)

@@ -2,19 +2,24 @@
 
 A cell (``BENCHMARK.json`` ``workloads``) names a configuration
 (``bench/configs/<config>.json``: the program's ``FLConfig`` fields, the
-model, and the generators of its data and client networks) and a
-traffic mix (``bench/traffic/<traffic>.json``: how the job is driven,
-``block_rounds`` rounds to a block); its comparison limits are in
+model, the generators of its data and client networks, and its plain
+reference) and a traffic mix (``bench/traffic/<traffic>.json``: how the
+job is driven, ``block_rounds`` rounds to a block). The configuration's
+model kind names its module, ``bench/models/<kind>.py``, which makes the
+data and the weights, builds the program and the reference (see
+``bench/models``); the cell's comparison limits are in
 ``bench/limits/<cell>.json`` and each per-layer metric is read by
-``bench/metrics/<metric>.py``. Nothing here names a cell, so a cell, a
-configuration or a metric is added by adding files.
+``bench/metrics/<metric>.py``. Nothing here names a cell or a model, so
+a cell, a configuration, a model kind or a metric is added by adding
+files.
 
 A run:
 
-1. set-up: make the data, the client networks and the weights from the
-   seed, build the program's ``FederatedServer``, and drive it through
-   its first three blocks: the first compiles, and all three are what
-   the comparison reads;
+1. set-up: the model's module makes the data, the client networks and
+   the weights from the seed and builds the program's
+   ``FederatedServer``; the harness drives it through its first three
+   blocks: the first compiles, and all three are what the comparison
+   reads;
 2. the window: blocks, each ``block_rounds`` rounds (the server's
    ``eval_every``), the flush of its logs and the fairness eval after
    it, as ``FederatedServer.run`` runs them, until ``--seconds`` have
@@ -28,7 +33,6 @@ A run:
 """
 from __future__ import annotations
 
-import dataclasses
 import glob
 import importlib.util
 import json
@@ -42,6 +46,8 @@ from types import SimpleNamespace
 from typing import Dict, List, Optional
 
 import numpy as np
+
+from bench import models
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
@@ -126,24 +132,6 @@ def device_info(chips: int, require_chip: bool) -> dict:
     return info
 
 
-# -- the program's configuration -----------------------------------------------
-
-def fl_config(fl: dict, seed: int, block_rounds: int):
-    """The program's ``FLConfig`` from a configuration's ``fl`` block;
-    nested blocks become the dataclass that field holds by default."""
-    from repro.core.server import FLConfig
-    base = FLConfig()
-    kw = {}
-    for k, v in fl.items():
-        default = getattr(base, k)
-        if isinstance(v, dict) and dataclasses.is_dataclass(default):
-            kw[k] = type(default)(**v)
-        else:
-            kw[k] = v
-    return FLConfig(**kw, seed=seed, n_rounds=10 ** 9,
-                    eval_every=block_rounds)
-
-
 # -- one run -------------------------------------------------------------------
 
 class CellRun:
@@ -151,38 +139,23 @@ class CellRun:
     block loop the window drives."""
 
     def __init__(self, spec: dict, seed: int, tracer=None):
-        import jax
-        from bench.reference import fl_round
-        from bench.traffic import networks, synthetic
-        from repro.core.server import FederatedServer
-        from repro.data.synthetic import FederatedDataset
-        from repro.network.trace import ClientNetworks
+        from bench.traffic import networks
 
         cfg = spec["config"]
         self.model, self.fl = cfg["model"], cfg["fl"]
+        self.module = models.load(self.model["kind"])
         self.k = spec["traffic"]["block_rounds"]
         self.C = self.fl["clients_per_round"]
         self.seed = seed % 2 ** 31
         self.tracer = tracer
         self.eval_s: List[float] = []
-        rng = np.random.default_rng(seed)
-        d = cfg["data"]
-        tx, ty, sx, sy = synthetic.generate(
-            rng, d["n_clients"], d["alpha"], d["beta"],
-            max_samples=d["max_samples"], test_frac=d["test_frac"],
-            sizes=d.get("sizes", "drawn"))
-        speed, loss = networks.generate(rng, d["n_clients"],
-                                        cfg["networks"])
-        self.train = synthetic.padded_train(tx, ty)
-        self.upload_mbps = speed
-        params = fl_round.init_params(
-            jax.random.PRNGKey(self.seed)[None], self.model)
-        self.params0 = {k: np.asarray(v[0]) for k, v in params.items()}
-        self.srv = FederatedServer(fl_config(self.fl, self.seed, self.k),
-                                   FederatedDataset(tx, ty, sx, sy),
-                                   ClientNetworks(speed, loss))
-        self.srv._state = self.srv.engine.init_state(
-            {k: v[0] for k, v in params.items()})
+        built = self.module.build(cfg, self.seed, np.random.default_rng(seed),
+                                  self.k, networks.generate)
+        self.srv = built.pop("srv")
+        # the rest is for the reference: params0, and what the module
+        # keeps for it, as attributes of the run too
+        self.built = built
+        vars(self).update(built)
 
     def _span(self, name):
         import contextlib
@@ -230,13 +203,7 @@ class CellRun:
 def reference(run: CellRun, **kw):
     """The plain reference of the run's configuration, data and seed;
     ``kw`` picks its matrix-product precision or plants a fault."""
-    from bench.reference import fl_round
-    fl = run.fl
-    scenario = {"seed": run.seed, "loss_rate": fl["tra"]["loss_rate"],
-                "recovery_policy": fl.get("recovery", {}).get(
-                    "policy", "one_shot")}
-    return fl_round.Reference(fl, run.model, [scenario], run.train,
-                              run.upload_mbps, **kw)
+    return run.module.reference(run.built, run.fl, run.model, **kw)
 
 
 class CompileCounter:

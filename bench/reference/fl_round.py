@@ -1,7 +1,8 @@
-"""Plain reference of the federated round the benchmark's configurations
-run: the same semantics as the engine's round step, written again in
-straightforward ``jax.numpy`` with no kernels and no import of the
-program.
+"""Plain reference of the federated round of the MLP configurations
+(model kind ``mlp``, ``bench/models/mlp.py``): the same semantics as the
+engine's round step with the LT-FL paper's MLP as the client model,
+written again in straightforward ``jax.numpy`` with no kernels and no
+import of the program.
 
 What a round does (one scenario; ``lax.scan`` strings rounds together,
 ``vmap`` runs a grid's scenarios side by side):

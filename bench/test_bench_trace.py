@@ -128,7 +128,8 @@ def test_robust_agg_bytes():
 def test_round_readers_take_device_time_from_the_trace():
     from types import SimpleNamespace
     from bench import harness
-    cfg = {"model": {"d_in": 60, "d_hidden": 128, "n_classes": 10},
+    cfg = {"model": {"kind": "mlp", "d_in": 60, "d_hidden": 128,
+                     "n_classes": 10},
            "fl": {"local_steps": 20, "batch_size": 32}}
     # 4 runs of a 10-round block program, 2 ms of device time in all
     trace = {"block_program_runs": 4, "block_program_s": 2e-3}

@@ -5,7 +5,7 @@ length."""
 import numpy as np
 import pytest
 
-from bench import harness
+from bench import harness, models
 from bench.traffic import networks, synthetic
 from repro.data.synthetic import generate_synthetic, stage_on_device
 from repro.network.trace import sample_networks
@@ -70,7 +70,8 @@ def test_every_cell_finds_its_files(name):
     speed, loss = networks.generate(np.random.default_rng(SEED), n,
                                     spec["config"]["networks"])
     assert speed.shape == loss.shape == (n,)
-    leaves = spec["config"]["model"]["param_order"]
+    model = spec["config"]["model"]
+    leaves = models.load(model["kind"]).leaves(model)
     assert set(spec["limits"]) <= {"loss_gap", "update_gap", "change_gap",
                                    "ef_gap", "update_leaf_gap",
                                    "change_leaf_gap"} | {
