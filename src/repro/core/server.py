@@ -326,19 +326,24 @@ class FederatedServer:
     def evaluate(self, params=None) -> FairnessReport:
         p = self.params if params is None else params
         with tele_mod.Span("fl.eval"):
+            # one put: the eval set goes up in one runtime call, as
+            # uncommitted arrays of its own dtypes, so the eval program
+            # compiles once
             with tele_mod.Span("fl.eval.upload"):
-                X, Y, W = (jnp.asarray(self.eval_X),
-                           jnp.asarray(self.eval_Y),
-                           jnp.asarray(self.eval_W))
-            acc, correct, n = self._eval_fn(p, self.frozen, X, Y, W)
+                X, Y, W = jax.device_put(
+                    (self.eval_X, self.eval_Y, self.eval_W))
+            out = self._eval_fn(p, self.frozen, X, Y, W)
+            # one fetch, as in RoundScanEngine.run_block: every output's
+            # copy is queued behind the program before the host waits
             with tele_mod.Span("fl.eval.copy"):
-                acc, correct, n = (np.asarray(acc), np.asarray(correct),
-                                   np.asarray(n))
+                for v in out:
+                    v.copy_to_host_async()
+                acc, correct, n = (np.asarray(v) for v in out)
             self._eval_fn.count(
                 h2d=self.eval_X.nbytes + self.eval_Y.nbytes
                 + self.eval_W.nbytes,
                 d2h=acc.nbytes + correct.nbytes + n.nbytes,
-                tokens=self._eval_tokens)
+                arrays=len(out), tokens=self._eval_tokens)
             with tele_mod.Span("fl.eval.report"):
                 return fairness_report(acc, n, correct)
 

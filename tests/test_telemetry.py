@@ -38,6 +38,7 @@ from jax.flatten_util import ravel_pytree
 from repro.checkpoint import load_checkpoint, save_checkpoint
 from repro.core import telemetry as tele_mod
 from repro.core.engine import _static_key, static_signature
+from repro.core.fairness import fairness_report
 from repro.core.mlp import mlp_init
 from repro.core.selection import SelectionConfig
 from repro.core.server import (FederatedServer, FLConfig, RoundLog,
@@ -409,7 +410,8 @@ LOG_KEYS = [(dict(tra_on=False), {"loss", "ids"}),
 
 
 class _RecordedLog:
-    """A block log that records when its copy starts and converts."""
+    """A block log or eval output that records when its copy starts and
+    converts."""
 
     def __init__(self, key, arr, calls):
         self.key, self.arr, self.calls = key, arr, calls
@@ -471,6 +473,71 @@ def test_run_block_counts_one_fetch_a_block(kw, keys, data, nets):
                                                     *b.values()])
     rec = eng.as_dict()
     assert (rec["d2h_fetches"], rec["d2h_arrays"]) == (2, 2 * len(keys))
+
+
+EVAL_OUTPUTS = ("acc", "correct", "n")
+
+
+@pytest.mark.parametrize("kw,keys", LOG_KEYS)
+def test_evaluate_starts_every_output_copy_before_converting(kw, keys,
+                                                             data, nets):
+    srv = FederatedServer(_cfg(rounds=2, **kw), data, nets)
+    srv._state, _ = srv.engine.run_block(srv._state, 0, 2)
+    real, calls, seen = srv._eval_fn, [], []
+
+    class Eval:
+        def __call__(self, *args):
+            out = real(*args)
+            seen.extend(out)
+            return tuple(_RecordedLog(k, v, calls)
+                         for k, v in zip(EVAL_OUTPUTS, out))
+
+        def count(self, **kw):
+            real.count(**kw)
+
+    srv._eval_fn = Eval()
+    report = srv.evaluate()
+    starts = [i for i, (op, _) in enumerate(calls) if op == "start"]
+    converts = [i for i, (op, _) in enumerate(calls) if op == "convert"]
+    assert sorted(k for op, k in calls if op == "start") \
+        == sorted(EVAL_OUTPUTS)
+    assert len(converts) == 3 and max(starts) < min(converts)
+    # the report the old way: three conversions of the real outputs
+    acc, correct, n = (np.asarray(v) for v in seen)
+    ref = fairness_report(acc, n, correct)
+    assert np.array(dataclasses.astuple(report)).tobytes() \
+        == np.array(dataclasses.astuple(ref)).tobytes()
+
+
+@pytest.mark.parametrize("kw,keys", LOG_KEYS)
+def test_evaluate_counts_one_fetch_an_eval(kw, keys, data, nets,
+                                           monkeypatch):
+    puts = []
+    real_put = jax.device_put
+
+    def put(x, *a, **k):
+        puts.append(x)
+        return real_put(x, *a, **k)
+
+    reg = ProgramRegistry()
+    real = tele_mod.REGISTRY
+    tele_mod.REGISTRY = reg
+    try:
+        srv = FederatedServer(_cfg(rounds=2, **kw), data, nets)
+        monkeypatch.setattr(jax, "device_put", put)
+        srv.evaluate()
+        srv.evaluate()
+    finally:
+        tele_mod.REGISTRY = real
+    ev = next(st for st in reg._stats.values() if st.kind == "eval")
+    assert (ev.d2h_fetches, ev.d2h_arrays) == (2, 6)
+    assert ev.h2d_bytes == 2 * (srv.eval_X.nbytes + srv.eval_Y.nbytes
+                                + srv.eval_W.nbytes)
+    assert ev.d2h_bytes == 2 * 3 * N_CLIENTS * 4
+    assert (ev.calls, ev.compiles) == (2, 1)
+    # one put an eval, of the whole eval set
+    assert len(puts) == 2
+    assert all(len(x) == 3 and x[0] is srv.eval_X for x in puts)
 
 
 # ---------------------------------------------------------------------------
