@@ -48,7 +48,8 @@ That is what lets `core/sweep.py` stack S scenarios behind a leading
 axis and ``vmap`` the *same* step function over them: a whole paper
 grid becomes one compiled program. Static structure (algorithm, debias
 mode, cohort size, local steps, batch size, TRA on/off, error
-feedback) stays in the closure and must be shared across a sweep.
+feedback, and the subsystems ``RoundPlan`` compiles in) stays in the
+closure and must be shared across a sweep.
 
 ``run_single`` jits the *same* step function for one round — that is the
 per-round reference path `FederatedServer.run_round` uses, which is what
@@ -158,7 +159,8 @@ class ScenarioCtx(NamedTuple):
     is vmapped over it. Anything NOT in here — algorithm, debias mode,
     cohort size, local steps, batch size, TRA enabled, error feedback,
     the netsim channel/bandwidth/deadline model *selection* — is baked
-    into the step closure and must be identical across a sweep.
+    into the step closure (``RoundPlan``) and must be identical across
+    a sweep. ``scenario_ctx`` builds one.
     """
     base_key: jnp.ndarray    # (2,) uint32 PRNG root of the fold_in chain
     loss_rate: jnp.ndarray   # () f32 nominal drop rate, or (N,) f32
@@ -332,6 +334,233 @@ def _static_key(cfg):
         robust_ops.resolved_impl(), fec_ops.resolved_impl())
 
 
+@dataclasses.dataclass(frozen=True)
+class RoundPlan:
+    """What one round's program compiles in, decided once from
+    ``(cfg, cohort)``: every static choice the step
+    (``make_round_step``), its carries (``init_engine_state``) and the
+    engines' input checks read. ``RoundPlan.of`` runs the
+    cross-subsystem config checks, so a plan exists only for a config
+    the step can compile. The knobs these choices leave free ride
+    ``ScenarioCtx``. Each switch's default compiles its subsystem out,
+    which keeps the frozen steps' locks (tests/_legacy_engine*.py)
+    bitwise."""
+    cfg: Any
+    cohort: int
+    # netsim models: Gilbert-Elliott channel, AR(1) bandwidth, deadline
+    use_ge: bool
+    use_bw: bool
+    use_dl: bool
+    # selection policy (or the traced family); the score memories the
+    # step writes for the next round; the bandwidth score's speed draw
+    traced_sel: bool
+    policy: str
+    need_gnorm: bool
+    need_loss: bool
+    need_stale: bool
+    need_rep: bool
+    need_speed_score: bool
+    # server mode (or traced); the K-slot arrival buffer; arrival-time
+    # aggregation (anything but plain sync)
+    traced_srv: bool
+    srv_mode: str
+    use_buf: bool
+    nonsync: bool
+    # the fault model and the trimmed mean's static extent
+    use_faults: bool
+    trim_k: int
+    # the recovery family (all three paths) and its FEC group size
+    use_rec: bool
+    rec_group: int
+    use_bud: bool       # the loss-budget controller
+    # downlink broadcast loss: on, GE channel, stale-model fallback
+    use_down: bool
+    down_ge: bool
+    down_stale: bool
+    tele_on: bool       # telemetry level above "off"
+
+    @classmethod
+    def of(cls, cfg, cohort: int) -> "RoundPlan":
+        ns, sel, srv, rec = cfg.netsim, cfg.sel, cfg.srv, cfg.recovery
+        use_faults = cfg.faults.enabled
+
+        def sel_uses(policy):   # the traced family holds every policy
+            return sel.traced or sel.policy == policy
+
+        plan = cls(
+            cfg=cfg, cohort=cohort,
+            use_ge=ns.channel == "gilbert_elliott", use_bw=ns.bw_ar1,
+            use_dl=ns.deadline,
+            traced_sel=sel.traced, policy=sel.policy,
+            need_gnorm=sel_uses("gradient_norm"),
+            need_loss=sel_uses("loss_aware"),
+            need_stale=sel_uses("staleness_aware"),
+            need_rep=use_faults and sel_uses("reputation_aware"),
+            need_speed_score=sel_uses("bandwidth_threshold"),
+            traced_srv=srv.traced, srv_mode=srv.mode,
+            use_buf=srv.traced or srv.mode == "async",
+            nonsync=srv.traced or srv.mode != "sync",
+            use_faults=use_faults, trim_k=cfg.defense.trim_k,
+            use_rec=rec.traced or rec.policy != "one_shot",
+            rec_group=rec.group, use_bud=cfg.lossbudget.enabled,
+            use_down=ns.down_channel != "off",
+            down_ge=ns.down_channel == "gilbert_elliott",
+            down_stale=ns.down_fallback == "stale",
+            tele_on=cfg.telemetry.level != "off")
+        plan._check()
+        return plan
+
+    def _check(self) -> None:
+        """Cross-subsystem static-config checks, raised at engine
+        construction (NOT inside the program cache: the cache key
+        normalises sweep-varying fields away, so these must run before
+        any cache lookup)."""
+        cfg = self.cfg
+        tra_cfg = cfg.tra
+        ns = cfg.netsim
+        debias = tra_cfg.debias
+        if ns.channel != "iid" and not tra_cfg.enabled:
+            raise ValueError(
+                f"netsim channel={ns.channel!r} models lossy TRA uploads "
+                f"and requires tra.enabled=True (with TRA off, uploads are "
+                f"reliable and the channel would be silently inert)")
+        # a policy's requirements bind only when it is the static choice
+        policy = None if self.traced_sel else self.policy
+        if policy == "netsim_state" and not self.use_ge:
+            raise ValueError(
+                "selection policy 'netsim_state' scores the Gilbert-"
+                "Elliott channel state and requires "
+                "netsim.channel='gilbert_elliott' (with the iid channel "
+                "there is no state to prefer)")
+        if policy == "staleness_aware" and not self.use_dl:
+            raise ValueError(
+                "selection policy 'staleness_aware' scores observed "
+                "deadline lateness and requires netsim.deadline=True "
+                "(without a deadline nothing is ever late)")
+        if self.nonsync and not self.use_dl:
+            raise ValueError(
+                "server modes semi_sync/async (and srv.traced, which "
+                "includes them) schedule uploads by arrival time and "
+                "require netsim.deadline=True")
+        if self.use_buf and debias == "per_coord_count":
+            raise ValueError(
+                "the async arrival buffer composes with scalar-"
+                "denominator debias modes only; per_coord_count keeps "
+                "per-coordinate denominators that cannot be re-weighted "
+                "after the fact (use semi_sync, or another debias mode)")
+        dfn_cfg = cfg.defense
+        trim_k = self.trim_k
+        if not self.use_faults and (dfn_cfg.screen or dfn_cfg.clip
+                                    or dfn_cfg.trim or trim_k > 0):
+            raise ValueError(
+                "defenses (screen/clip/trim/trim_k) require "
+                "faults.enabled=True — the robust uplink path is only "
+                "compiled with the fault model (enable it with zero rates "
+                "for a fault-free defended run)")
+        if dfn_cfg.trim and trim_k < 1:
+            raise ValueError(
+                "defense.trim=True needs trim_k >= 1 (the static per-side "
+                "trim count that sizes the extraction loop)")
+        if trim_k > 0 and debias == "per_coord_count":
+            raise ValueError(
+                "trimmed-mean aggregation replaces the weighted mean and "
+                "cannot compose with per_coord_count's per-coordinate "
+                "denominators (use another debias mode, or trim_k=0)")
+        if policy == "reputation_aware" and not self.use_faults:
+            raise ValueError(
+                "selection policy 'reputation_aware' scores quarantine "
+                "counts and requires faults.enabled=True (without the "
+                "fault path nothing is ever quarantined)")
+        if self.use_rec and not tra_cfg.enabled:
+            raise ValueError(
+                "recovery policies act on the lossy TRA uplink mask and "
+                "require tra.enabled=True (with TRA off, uploads are "
+                "reliable and there is nothing to recover)")
+        if self.use_bud and not cfg.recovery.traced:
+            raise ValueError(
+                "the loss-budget controller mixes recovery policies "
+                "per client and requires recovery.traced=True (all three "
+                "policies must be compiled into the step)")
+        if policy == "recovery_pressure" and not self.use_bud:
+            raise ValueError(
+                "selection policy 'recovery_pressure' scores the loss-"
+                "budget controller's escalation state and requires "
+                "lossbudget.enabled=True (without the controller there is "
+                "no pressure signal)")
+
+    def check_draws(self, packet_loss, upload_mbps) -> None:
+        """Refuse a scenario without the trace draws the program reads:
+        per-client loss rates, and the upload speeds that the bandwidth
+        and deadline models start from and the bandwidth score reads."""
+        if self.cfg.tra.per_client_loss and packet_loss is None:
+            raise ValueError("tra.per_client_loss needs the trace "
+                             "draw (pass nets.packet_loss)")
+        if (self.use_bw or self.use_dl) and upload_mbps is None:
+            raise ValueError("netsim bandwidth/deadline models need "
+                             "the trace draw (pass nets.upload_mbps)")
+        if self.need_speed_score and upload_mbps is None:
+            raise ValueError(
+                "the bandwidth_threshold selection score (and the "
+                "traced policy family, which includes it) needs the "
+                "trace draw (pass nets.upload_mbps)")
+
+
+def validate_round_config(cfg) -> None:
+    """Raise the ``ValueError`` of the first cross-subsystem check
+    ``cfg`` fails (``RoundPlan.of`` runs them)."""
+    RoundPlan.of(cfg, cfg.clients_per_round)
+
+
+def scenario_ctx(cfg, eligible, sufficient, data, packet_loss=None,
+                 upload_mbps=None, frozen=None) -> ScenarioCtx:
+    """The traced inputs of one scenario: ``cfg``'s scenario knobs, its
+    masks and trace draws, the staged ``data`` and the client model's
+    ``frozen`` tree. The one place a ``ScenarioCtx`` is built: the
+    sweep stacks one per scenario."""
+    ns, sel, srv = cfg.netsim, cfg.sel, cfg.srv
+    flt, dfn, rec, bud = cfg.faults, cfg.defense, cfg.recovery, \
+        cfg.lossbudget
+    return ScenarioCtx(
+        base_key=jax.random.PRNGKey(cfg.seed),
+        loss_rate=jnp.asarray(np.asarray(packet_loss, np.float32))
+        if cfg.tra.per_client_loss else jnp.float32(cfg.tra.loss_rate),
+        eligible=jnp.asarray(np.asarray(eligible, bool)),
+        sufficient=jnp.asarray(np.asarray(sufficient, np.float32)),
+        data=data,
+        burst_len=jnp.float32(ns.burst_len),
+        good_loss=jnp.float32(ns.good_loss),
+        bad_loss=jnp.float32(ns.bad_loss),
+        bw_rho=jnp.float32(ns.bw_rho),
+        deadline_s=jnp.float32(ns.deadline_s),
+        sel_threshold=jnp.float32(sel.threshold_mbps),
+        sel_temp=jnp.float32(sel.temperature),
+        sel_explore=jnp.float32(sel.explore),
+        sel_policy=jnp.asarray(sel_mod.policy_onehot(sel.policy)),
+        sel_logbw=log_upload_speeds(np.asarray(upload_mbps, np.float32))
+        if upload_mbps is not None else jnp.zeros((0,), jnp.float32),
+        srv_mode=jnp.asarray(async_mod.mode_onehot(srv.mode)),
+        stale_alpha=jnp.float32(srv.staleness_alpha),
+        grace_s=jnp.float32(srv.grace_s),
+        f_corrupt=jnp.float32(flt.corrupt_rate),
+        f_cscale=jnp.float32(flt.corrupt_scale),
+        f_bitflip=jnp.float32(flt.bitflip_rate),
+        f_fail=jnp.float32(flt.fail_rate),
+        f_flip=jnp.float32(flt.flip_rate),
+        f_echo=jnp.float32(flt.echo_rate),
+        d_screen=jnp.float32(1.0 if dfn.screen else 0.0),
+        d_clip=jnp.float32(faults_mod.clip_knob(dfn)),
+        d_trim=jnp.float32(1.0 if dfn.trim else 0.0),
+        down_loss=jnp.float32(ns.down_loss),
+        down_deadline_s=jnp.float32(ns.down_deadline_s),
+        rec_policy=jnp.asarray(rec_mod.recovery_onehot(rec.policy)),
+        rec_retries=jnp.float32(rec.retries),
+        rec_backoff=jnp.float32(rec.backoff),
+        bud_budget=jnp.float32(bud.budget),
+        bud_ema=jnp.float32(bud.ema),
+        bud_div=jnp.float32(bud.div_gate),
+        frozen=frozen)
+
+
 # step/jit cache shared across engine instances: scenario-varying values
 # are traced ScenarioCtx arguments, so every engine (and server) with the
 # same static config reuses ONE compiled program per input shape instead
@@ -340,13 +569,12 @@ def _static_key(cfg):
 _STEP_CACHE: Dict[Any, Any] = {}
 
 
-def _cached_jits(cfg, cohort: int):
-    # validate BEFORE the cache lookup: the key normalises sweep-
-    # varying fields away, so an invalid config (e.g. defenses with
-    # faults.enabled=False) can collide with a valid cached program
-    # and would otherwise skip its construction-time checks
-    validate_round_config(cfg)
-    key = (_static_key(cfg), cohort)
+def _cached_jits(plan: RoundPlan):
+    # the plan exists, so its config passed the construction-time
+    # checks: the key normalises sweep-varying fields away, so an
+    # invalid config (e.g. defenses with faults.enabled=False) could
+    # collide with a valid cached program and skip them otherwise
+    key = (_static_key(plan.cfg), plan.cohort)
     hit = key in _STEP_CACHE
     # every lookup logs the static-signature fingerprint (hit or
     # insert) to the program registry — two configs silently colliding
@@ -354,7 +582,7 @@ def _cached_jits(cfg, cohort: int):
     # timing wrapper books compile/exec time against the same key.
     fp = tele_mod.REGISTRY.record_lookup("engine", key, hit=hit)
     if not hit:
-        step = make_round_step(cfg, cohort)
+        step = make_round_step(plan.cfg, plan.cohort)
 
         # a named function, so the compiled module (and its runs in a
         # profiler trace) is ``jit_round_block``
@@ -370,19 +598,21 @@ def _cached_jits(cfg, cohort: int):
     return _STEP_CACHE[key]
 
 
-def init_engine_state(cfg, params, n_clients: int, *, base_key=None,
-                      loss_rate=None, upload_mbps=None,
+def init_engine_state(plan: RoundPlan, params, n_clients: int, *,
+                      base_key, loss_rate, upload_mbps=None,
                       netsim=None) -> EngineState:
     """Fresh engine state for one scenario (used by both the single
     engine and, stacked, by the sweep engine). ``params`` are copied:
     the engine jits DONATE the state, and the caller's arrays must not
-    be destroyed with it.
+    be destroyed with it. A carry is allocated where ``plan`` compiles
+    in the stage that reads it; the rest are zero-size.
 
     The netsim carry (Gilbert–Elliott channel states, log-bandwidth
     levels) initialises from the scenario's PRNG root / loss rate /
-    static speed draw; the defaults reconstruct the single-engine
-    values from ``cfg`` so existing callers stay source-compatible.
+    static speed draw and its ``netsim`` knobs (default
+    ``plan.cfg.netsim``).
     """
+    cfg = plan.cfg
     N = n_clients
     params = jax.tree.map(lambda x: jnp.array(x, copy=True), params)
     vec0 = ravel_pytree(params)[0]
@@ -390,10 +620,11 @@ def init_engine_state(cfg, params, n_clients: int, *, base_key=None,
     # SCAFFOLD uploads (dw ++ dc) ride one TRA stream, so its EF
     # memory covers the concatenated 2D vector.
     up_dim = 2 * D if cfg.algo == "scaffold" else D
-    if base_key is None:
-        base_key = jax.random.PRNGKey(cfg.seed)
-    if loss_rate is None:
-        loss_rate = jnp.float32(cfg.tra.loss_rate)
+
+    def per_client(on):
+        return jnp.zeros((N,), jnp.float32) if on \
+            else jnp.zeros((0,), jnp.float32)
+
     return EngineState(
         params=params,
         ef_mem=jnp.zeros((N, up_dim), jnp.float32)
@@ -406,125 +637,23 @@ def init_engine_state(cfg, params, n_clients: int, *, base_key=None,
         net=init_net_state(cfg.netsim if netsim is None else netsim, N,
                            base_key=base_key, loss_rate=loss_rate,
                            upload_mbps=upload_mbps),
-        gnorm_mem=jnp.zeros((N,), jnp.float32)
-        if cfg.sel.traced or cfg.sel.policy == "gradient_norm"
-        else jnp.zeros((0,), jnp.float32),
-        loss_mem=jnp.zeros((N,), jnp.float32)
-        if cfg.sel.traced or cfg.sel.policy == "loss_aware"
-        else jnp.zeros((0,), jnp.float32),
-        stale_mem=jnp.zeros((N,), jnp.float32)
-        if cfg.sel.traced or cfg.sel.policy == "staleness_aware"
-        else jnp.zeros((0,), jnp.float32),
+        gnorm_mem=per_client(plan.need_gnorm),
+        loss_mem=per_client(plan.need_loss),
+        stale_mem=per_client(plan.need_stale),
         buf=async_mod.init_arrival_buffer(cfg.srv.buffer_k, up_dim)
-        if cfg.srv.traced or cfg.srv.mode == "async"
-        else async_mod.empty_arrival_buffer(),
+        if plan.use_buf else async_mod.empty_arrival_buffer(),
         echo_mem=jnp.zeros((N, up_dim), jnp.float32)
-        if cfg.faults.enabled else jnp.zeros((0,), jnp.float32),
-        rep_mem=jnp.zeros((N,), jnp.float32)
-        if cfg.faults.enabled
-        and (cfg.sel.traced or cfg.sel.policy == "reputation_aware")
-        else jnp.zeros((0,), jnp.float32),
+        if plan.use_faults else jnp.zeros((0,), jnp.float32),
+        rep_mem=per_client(plan.need_rep),
         tele=tele_mod.init_telemetry_state(cfg.telemetry, N),
         # every client starts having "received" the initial broadcast
         # (training begins from a known, fully-delivered init)
         stale_model=jnp.tile(vec0.astype(jnp.float32)[None, :], (N, 1))
-        if (cfg.netsim.down_channel != "off"
-            and cfg.netsim.down_fallback == "stale")
+        if plan.use_down and plan.down_stale
         else jnp.zeros((0,), jnp.float32),
-        bud_level=jnp.zeros((N,), jnp.float32)
-        if cfg.lossbudget.enabled else jnp.zeros((0,), jnp.float32),
-        bud_loss=jnp.zeros((N,), jnp.float32)
-        if cfg.lossbudget.enabled else jnp.zeros((0,), jnp.float32),
+        bud_level=per_client(plan.use_bud),
+        bud_loss=per_client(plan.use_bud),
     )
-
-
-def validate_round_config(cfg) -> None:
-    """Cross-subsystem static-config checks, raised at engine
-    construction (NOT inside the program cache: the cache key
-    normalises sweep-varying fields away, so these must run before
-    any cache lookup)."""
-    tra_cfg = cfg.tra
-    ns = cfg.netsim
-    debias = tra_cfg.debias
-    if ns.channel != "iid" and not tra_cfg.enabled:
-        raise ValueError(
-            f"netsim channel={ns.channel!r} models lossy TRA uploads "
-            f"and requires tra.enabled=True (with TRA off, uploads are "
-            f"reliable and the channel would be silently inert)")
-    sel = cfg.sel
-    traced_sel = sel.traced
-    policy = sel.policy
-    if not traced_sel and policy == "netsim_state" \
-            and ns.channel != "gilbert_elliott":
-        raise ValueError(
-            "selection policy 'netsim_state' scores the Gilbert-"
-            "Elliott channel state and requires "
-            "netsim.channel='gilbert_elliott' (with the iid channel "
-            "there is no state to prefer)")
-    if not traced_sel and policy == "staleness_aware" \
-            and not ns.deadline:
-        raise ValueError(
-            "selection policy 'staleness_aware' scores observed "
-            "deadline lateness and requires netsim.deadline=True "
-            "(without a deadline nothing is ever late)")
-    srv_cfg = cfg.srv
-    nonsync = srv_cfg.traced or srv_cfg.mode != "sync"
-    use_buf = srv_cfg.traced or srv_cfg.mode == "async"
-    if nonsync and not ns.deadline:
-        raise ValueError(
-            "server modes semi_sync/async (and srv.traced, which "
-            "includes them) schedule uploads by arrival time and "
-            "require netsim.deadline=True")
-    if use_buf and debias == "per_coord_count":
-        raise ValueError(
-            "the async arrival buffer composes with scalar-"
-            "denominator debias modes only; per_coord_count keeps "
-            "per-coordinate denominators that cannot be re-weighted "
-            "after the fact (use semi_sync, or another debias mode)")
-    dfn_cfg = cfg.defense
-    use_faults = cfg.faults.enabled
-    trim_k = dfn_cfg.trim_k
-    if not use_faults and (dfn_cfg.screen or dfn_cfg.clip
-                           or dfn_cfg.trim or trim_k > 0):
-        raise ValueError(
-            "defenses (screen/clip/trim/trim_k) require "
-            "faults.enabled=True — the robust uplink path is only "
-            "compiled with the fault model (enable it with zero rates "
-            "for a fault-free defended run)")
-    if dfn_cfg.trim and trim_k < 1:
-        raise ValueError(
-            "defense.trim=True needs trim_k >= 1 (the static per-side "
-            "trim count that sizes the extraction loop)")
-    if trim_k > 0 and debias == "per_coord_count":
-        raise ValueError(
-            "trimmed-mean aggregation replaces the weighted mean and "
-            "cannot compose with per_coord_count's per-coordinate "
-            "denominators (use another debias mode, or trim_k=0)")
-    if not traced_sel and policy == "reputation_aware" \
-            and not use_faults:
-        raise ValueError(
-            "selection policy 'reputation_aware' scores quarantine "
-            "counts and requires faults.enabled=True (without the "
-            "fault path nothing is ever quarantined)")
-    rec_cfg = cfg.recovery
-    use_rec = rec_cfg.traced or rec_cfg.policy != "one_shot"
-    if use_rec and not tra_cfg.enabled:
-        raise ValueError(
-            "recovery policies act on the lossy TRA uplink mask and "
-            "require tra.enabled=True (with TRA off, uploads are "
-            "reliable and there is nothing to recover)")
-    if cfg.lossbudget.enabled and not rec_cfg.traced:
-        raise ValueError(
-            "the loss-budget controller mixes recovery policies "
-            "per client and requires recovery.traced=True (all three "
-            "policies must be compiled into the step)")
-    if not traced_sel and policy == "recovery_pressure" \
-            and not cfg.lossbudget.enabled:
-        raise ValueError(
-            "selection policy 'recovery_pressure' scores the loss-"
-            "budget controller's escalation state and requires "
-            "lossbudget.enabled=True (without the controller there is "
-            "no pressure signal)")
 
 
 # The round's stages, in the order the step runs them. Each names a
@@ -563,8 +692,10 @@ def make_round_step(cfg, cohort: int):
     what ``SweepEngine`` vmaps over a stacked ctx/state for S scenarios
     in one program. N (client count), M (padded set length) and the
     model dimension come from the traced shapes, so the same step works
-    for any same-shaped scenario.
+    for any same-shaped scenario. What the step compiles in is
+    ``RoundPlan.of(cfg, cohort)``, read as ``plan.*``.
     """
+    plan = RoundPlan.of(cfg, cohort)
     tra_cfg = cfg.tra
     hyper = cfg.hyper()
     model = cfg.model
@@ -575,64 +706,7 @@ def make_round_step(cfg, cohort: int):
     F = tra_cfg.packet_floats
     debias = tra_cfg.debias
     local = None if algo == "scaffold" else cu.LOCAL_FNS[algo]
-    # netsim model selection is static (program structure); its knobs
-    # (burst length, loss emissions, rho, deadline) are traced ctx
-    # fields and may vary per scenario.
-    ns = cfg.netsim
-    validate_round_config(cfg)
-    use_ge = ns.channel == "gilbert_elliott"
-    use_bw = ns.bw_ar1
-    use_dl = ns.deadline
-    # selection policy: the id (or "traced") is static program
-    # structure; its knobs ride ScenarioCtx (core/selection.py)
-    sel = cfg.sel
-    traced_sel = sel.traced
-    policy = sel.policy
-    need_gnorm = traced_sel or policy == "gradient_norm"
-    need_loss = traced_sel or policy == "loss_aware"
-    need_stale = traced_sel or policy == "staleness_aware"
-    # server aggregation mode (core/async_agg.py): the mode (or
-    # "traced") and the buffer size are static program structure; the
-    # staleness exponent and grace window ride ScenarioCtx.
-    srv_cfg = cfg.srv
-    traced_srv = srv_cfg.traced
-    srv_mode = srv_cfg.mode
-    use_buf = traced_srv or srv_mode == "async"
-    nonsync = traced_srv or srv_mode != "sync"
-    # fault model + defenses (repro/netsim/faults.py):
-    # ``faults.enabled`` is the single static switch for the whole
-    # subsystem; every rate and every defense gate is traced.
-    # ``defense.trim_k`` alone is static (extraction-loop extent).
-    flt_cfg = cfg.faults
-    dfn_cfg = cfg.defense
-    use_faults = flt_cfg.enabled
-    trim_k = dfn_cfg.trim_k
-    need_rep = use_faults and (traced_sel
-                               or policy == "reputation_aware")
-    # telemetry level is static program structure (core/telemetry.py):
-    # "off" compiles the subsystem out entirely — the step below is
-    # then bitwise the frozen PR-8 step (tests/_legacy_engine_v8.py).
-    tele_cfg = cfg.telemetry
-    tele_on = tele_cfg.level != "off"
-    # recovery-policy family (netsim/recovery.py): the policy (or
-    # "traced") and the FEC group size are static program structure;
-    # retries/backoff ride ScenarioCtx. ``use_rec`` compiles all three
-    # recovery paths in — the one_shot default compiles them OUT and
-    # is bitwise the frozen PR-9 step (tests/_legacy_engine_v9.py).
-    rec_cfg = cfg.recovery
-    use_rec = rec_cfg.traced or rec_cfg.policy != "one_shot"
-    rec_group = rec_cfg.group
     n_pol = len(rec_mod.RECOVERY_POLICIES)
-    # adaptive loss-budget controller (core/lossbudget.py): enabled is
-    # the single static switch; budget/ema/div_gate are traced.
-    use_bud = cfg.lossbudget.enabled
-    # downlink broadcast loss (netsim): the channel choice and the
-    # fallback are static; down_loss / down_deadline_s are traced. The
-    # "off" default broadcasts losslessly — shared params, bitwise the
-    # frozen PR-9 step.
-    use_down = ns.down_channel != "off"
-    down_ge = ns.down_channel == "gilbert_elliott"
-    down_stale = ns.down_fallback == "stale"
 
     def step(ctx: ScenarioCtx, state: EngineState, t):
         stage = _StageScope()
@@ -662,7 +736,7 @@ def make_round_step(cfg, cohort: int):
         D_up = 2 * D_model if algo == "scaffold" else D_model
         P = n_packets(D_up, F)
         n_batch = C * steps * bs
-        n_tra = 2 * C * P if use_ge else C * P
+        n_tra = 2 * C * P if plan.use_ge else C * P
         # recovery / downlink blocks are APPENDED after the legacy
         # slices. NOTE: threefry uniforms are NOT prefix-stable in the
         # total draw count, so what keeps the default programs bitwise
@@ -670,11 +744,11 @@ def make_round_step(cfg, cohort: int):
         # what makes a traced recovery grid cell bitwise equal to its
         # static single run is that both programs draw the SAME total
         # (use_rec always draws both the ARQ and the parity blocks).
-        gn = rec_mod.fec_groups(P, rec_group) if use_rec else 0
-        n_rec = C * P + C * gn if use_rec else 0
+        gn = rec_mod.fec_groups(P, plan.rec_group) if plan.use_rec else 0
+        n_rec = C * P + C * gn if plan.use_rec else 0
         P_dn = n_packets(D_model, F)
-        n_down = (2 * C * P_dn if down_ge else C * P_dn) \
-            if use_down else 0
+        n_down = (2 * C * P_dn if plan.down_ge else C * P_dn) \
+            if plan.use_down else 0
         key = jax.random.fold_in(ctx.base_key, t)
         u_all = jax.random.uniform(
             key, (N + n_batch + n_tra + n_rec + n_down,),
@@ -684,17 +758,17 @@ def make_round_step(cfg, cohort: int):
         u_tra = u_all[N + n_batch:N + n_batch + C * P].reshape(C, P)
         u_emit = u_all[N + n_batch + C * P:
                        N + n_batch + n_tra].reshape(C, P) \
-            if use_ge else None
+            if plan.use_ge else None
         off = N + n_batch + n_tra
         u_arq = u_par = None
-        if use_rec:
+        if plan.use_rec:
             u_arq = u_all[off:off + C * P].reshape(C, P)
             u_par = u_all[off + C * P:off + n_rec].reshape(C, gn)
             off += n_rec
         u_dt = u_de = None
-        if use_down:
+        if plan.use_down:
             u_dt = u_all[off:off + C * P_dn].reshape(C, P_dn)
-            if down_ge:
+            if plan.down_ge:
                 u_de = u_all[off + C * P_dn:
                              off + 2 * C * P_dn].reshape(C, P_dn)
 
@@ -704,8 +778,8 @@ def make_round_step(cfg, cohort: int):
         # score memory) — selection happens before this round's
         # training, exactly like a real server. policy="uniform"
         # (logits None) evaluates the legacy expression bitwise.
-        sel_bw = state.net.logbw if use_bw else ctx.sel_logbw
-        if traced_sel:
+        sel_bw = state.net.logbw if plan.use_bw else ctx.sel_logbw
+        if plan.traced_sel:
             logits = sel_mod.traced_policy_logits(
                 ctx.sel_policy, temperature=ctx.sel_temp,
                 explore=ctx.sel_explore,
@@ -716,7 +790,7 @@ def make_round_step(cfg, cohort: int):
                 bud_loss=state.bud_loss, n_clients=N)
         else:
             logits = sel_mod.policy_logits(
-                policy, temperature=ctx.sel_temp,
+                plan.policy, temperature=ctx.sel_temp,
                 explore=ctx.sel_explore,
                 threshold_mbps=ctx.sel_threshold, logbw=sel_bw,
                 gnorm_mem=state.gnorm_mem, loss_mem=state.loss_mem,
@@ -749,8 +823,8 @@ def make_round_step(cfg, cohort: int):
         net_down = state.net.down
         eff_vec = None      # (C, D_model) per-client effective params
         dn_frac = None      # realized downlink loss (telemetry)
-        if use_down:
-            if down_ge:
+        if plan.use_down:
+            if plan.down_ge:
                 dp_gb, dp_bg = ge_transition_probs(
                     ctx.down_loss, ctx.burst_len, ctx.good_loss,
                     ctx.bad_loss)
@@ -760,7 +834,7 @@ def make_round_step(cfg, cohort: int):
                 net_down = net_down.at[ids].set(ds_fin)
             else:
                 dmask = (u_dt >= ctx.down_loss).astype(jnp.float32)
-            if use_bw or use_dl:
+            if plan.use_bw or plan.use_dl:
                 # broadcast deadline: the whole model misses when
                 # pushing P_dn packets at the client's current
                 # (carried) bandwidth overruns the traced gate; <= 0
@@ -775,7 +849,7 @@ def make_round_step(cfg, cohort: int):
                     1.0)
                 dmask = dmask * dok[:, None]
             coord_dn = jnp.repeat(dmask, F, axis=1)[:, :D_model]
-            stale_rows = state.stale_model[ids] if down_stale \
+            stale_rows = state.stale_model[ids] if plan.down_stale \
                 else jnp.zeros((C, D_model), jnp.float32)
             eff_vec = coord_dn * old_vec[None, :] \
                 + (1.0 - coord_dn) * stale_rows
@@ -792,7 +866,7 @@ def make_round_step(cfg, cohort: int):
                 return cu.scaffold_local(p, x, y, c_global, ci, hyper,
                                          loss)
 
-            if use_down:
+            if plan.use_down:
                 uploads, aux = jax.vmap(
                     lambda pv, x, y, ci_vec: loc(
                         unflatten_like(pv, params), x, y, ci_vec),
@@ -805,7 +879,7 @@ def make_round_step(cfg, cohort: int):
             dc = flatten_clients(uploads["dc"], C)
             flat = jnp.concatenate([dw, dc], axis=1)         # (C, 2D)
         else:
-            if use_down:
+            if plan.use_down:
                 uploads, aux = jax.vmap(
                     lambda pv, x, y: local(
                         unflatten_like(pv, params), x, y, hyper, loss),
@@ -824,7 +898,7 @@ def make_round_step(cfg, cohort: int):
         # engine's selection/batch/TRA draws are untouched; zero rates
         # pass ``flat`` through bitwise.
         flat_clean = flat
-        if use_faults:
+        if plan.use_faults:
             fkey = jax.random.fold_in(key, faults_mod.FAULT_FOLD)
             flat = faults_mod.inject_client_faults(
                 fkey, flat, state.echo_mem[ids],
@@ -862,11 +936,11 @@ def make_round_step(cfg, cohort: int):
         def _apply_recovery(base_mask):
             par_mask = rec_mod.fec_parity_mask(u_par, lr_col)
             mask_fec = fec_ops.fec_recover(base_mask, par_mask,
-                                           group=rec_group)
+                                           group=plan.rec_group)
             mask_arq = rec_mod.arq_residual_mask(
                 base_mask, u_arq, lr_col, ctx.rec_retries)
             oh = bud_mod.controller_policy_onehot(
-                state.bud_level[ids]) if use_bud \
+                state.bud_level[ids]) if plan.use_bud \
                 else jnp.broadcast_to(ctx.rec_policy[None, :],
                                       (C, n_pol))
             mask_eff = oh[:, 0:1] * base_mask \
@@ -876,7 +950,7 @@ def make_round_step(cfg, cohort: int):
                      (mask_arq - base_mask).mean())
             return mask_eff, stats
 
-        if use_ge:
+        if plan.use_ge:
             # bursty loss: advance each cohort client's two-state
             # channel by P packet-steps (kernels/netsim_mask; Pallas
             # on TPU, jnp scan reference elsewhere) and scatter the
@@ -889,12 +963,12 @@ def make_round_step(cfg, cohort: int):
                 u_tra, u_emit, net_channel[ids], p_gb, p_bg,
                 ctx.good_loss, ctx.bad_loss)
             net_channel = net_channel.at[ids].set(s_fin)
-            if use_rec:
+            if plan.use_rec:
                 ge_mask, (rec_oh, realized_c, fec_frac, arq_frac) = \
                     _apply_recovery(ge_mask)
             pkt_mask = jnp.where(suff.astype(bool)[:, None], 1.0,
                                  ge_mask)
-        elif tra_cfg.enabled and use_rec:
+        elif tra_cfg.enabled and plan.use_rec:
             chan_mask = (u_tra >= lr_col).astype(jnp.float32)
             mask_eff, (rec_oh, realized_c, fec_frac, arq_frac) = \
                 _apply_recovery(chan_mask)
@@ -913,10 +987,10 @@ def make_round_step(cfg, cohort: int):
         # rate after ARQ repaired most losses over-inflates every
         # insufficient client by 1/(1-r) and diverges. one_shot rows
         # mix to exactly r, so that cell keeps the legacy estimator.
-        lr_deb = lr_c if not use_rec else rec_mod.residual_rate_mixed(
-            rec_oh, lr_c, ctx.rec_retries, rec_group)
+        lr_deb = lr_c if not plan.use_rec else rec_mod.residual_rate_mixed(
+            rec_oh, lr_c, ctx.rec_retries, plan.rec_group)
 
-        if use_bw:
+        if plan.use_bw:
             # time passes for every client, not just the cohort: one
             # AR(1) step on all N log-bandwidth levels per round
             net_logbw = logbw_round_step(key, net_logbw, ctx.bw_rho)
@@ -927,18 +1001,18 @@ def make_round_step(cfg, cohort: int):
         a_c = None          # per-client arrival weight on w_agg
         arrival = None      # logged effective arrival weight (C,)
         lateness = None     # rounds late (staleness memory + buffer)
-        if use_dl:
+        if plan.use_dl:
             # arrival times: current bandwidth + packets sent
             # (retransmitters push ~P/(1-r), TRA one-shots push P)
             retransmit = suff.astype(bool) if tra_cfg.enabled \
                 else jnp.ones((C,), bool)
-            if use_rec:
+            if plan.use_rec:
                 # each policy pays its airtime: FEC ships 1 + 1/G
                 # model-equivalents, ARQ the expected retry traffic;
                 # retransmitters (sufficient clients) still pay the
                 # legacy P/(1-r) regardless of policy.
                 sends_pol = rec_oh[:, 0] * 1.0 \
-                    + rec_oh[:, 1] * rec_mod.fec_sends(rec_group) \
+                    + rec_oh[:, 1] * rec_mod.fec_sends(plan.rec_group) \
                     + rec_oh[:, 2] * rec_mod.arq_sends(
                         lr_c, ctx.rec_retries, ctx.rec_backoff)
                 secs = rec_mod.recovery_upload_seconds(
@@ -948,9 +1022,9 @@ def make_round_step(cfg, cohort: int):
                 secs = round_upload_seconds(
                     P, F, jnp.exp(net_logbw[ids]), lr_c, retransmit)
             delivered = deadline_delivered(secs, ctx.deadline_s)
-            if need_stale or nonsync or tele_on:
+            if plan.need_stale or plan.nonsync or plan.tele_on:
                 lateness = arrival_lateness(secs, ctx.deadline_s)
-            if not nonsync:
+            if not plan.nonsync:
                 # sync: a miss drops the WHOLE upload (row of zeros —
                 # EF captures it when enabled); the straggler's weight
                 # still enters the denominator, biasing the round the
@@ -983,7 +1057,7 @@ def make_round_step(cfg, cohort: int):
                 w_late = async_mod.staleness_weight(lateness,
                                                     ctx.stale_alpha)
                 a_async_log = ontime + late * feasible * w_late
-                if traced_srv:
+                if plan.traced_srv:
                     is_sync = ctx.srv_mode[0] > 0.5
                     is_semi = ctx.srv_mode[1] > 0.5
                     is_async = ctx.srv_mode[2] > 0.5
@@ -1001,7 +1075,7 @@ def make_round_step(cfg, cohort: int):
                     arrival = jnp.where(
                         is_sync, delivered,
                         jnp.where(is_semi, a_semi, a_async_log))
-                elif srv_mode == "semi_sync":
+                elif plan.srv_mode == "semi_sync":
                     pkt_mask = loss_mask * within[:, None]
                     a_c = a_semi
                     arrival = a_semi
@@ -1013,14 +1087,14 @@ def make_round_step(cfg, cohort: int):
         # the packets the channel/deadline actually DELIVERS (a lost
         # packet never reaches the server, so EF recycling stays
         # clean). Zero rates pass ``xp`` through bitwise.
-        if use_faults:
+        if plan.use_faults:
             xp = faults_mod.inject_packet_faults(
                 fkey, xp, pkt_mask, corrupt_rate=ctx.f_corrupt,
                 corrupt_scale=ctx.f_cscale,
                 bitflip_rate=ctx.f_bitflip)
 
         kept = None
-        if debias == "per_client_rate" and not use_faults:
+        if debias == "per_client_rate" and not plan.use_faults:
             # coordinate-weighted kept fraction (last packet partial);
             # the fault path computes this from the SCREENED mask
             # inside robust_uplink_round instead
@@ -1041,7 +1115,7 @@ def make_round_step(cfg, cohort: int):
         # masked norms the megakernel computes in this same pass; the
         # loss-budget controller reads the same norms as its
         # divergence signal
-        want_ssq = want_ssq or need_gnorm or use_bud
+        want_ssq = want_ssq or plan.need_gnorm or plan.use_bud
         # non-sync modes fold the arrival weight into the aggregation
         # weights: zero-weight stragglers leave BOTH the numerator and
         # the denominator (the EF update and ssq are weight-free in
@@ -1050,7 +1124,7 @@ def make_round_step(cfg, cohort: int):
         # multiply, bitwise legacy.
         w_up = w_agg if a_c is None else w_agg * a_c
 
-        if use_faults:
+        if plan.use_faults:
             # defended uplink (kernels/robust_agg): finite-screen
             # quarantine (bad packets become AS IF LOST — same debias
             # machinery), norm clip, trimmed mean — every gate traced,
@@ -1058,7 +1132,7 @@ def make_round_step(cfg, cohort: int):
             rob = robust_ops.robust_uplink_round(
                 xp, pkt_mask, w_up, mode=debias, d_up=D_up,
                 screen=ctx.d_screen, clip_norm=ctx.d_clip,
-                trim_gate=ctx.d_trim, trim_k=trim_k,
+                trim_gate=ctx.d_trim, trim_k=plan.trim_k,
                 ef_rows=state.ef_mem[ids] if ef else None,
                 sufficient=suff, loss_rate=lr_deb, mult=mult,
                 want_ssq=want_ssq)
@@ -1079,7 +1153,7 @@ def make_round_step(cfg, cohort: int):
         # aggregate, push this round's late uploads (core/async_agg.py)
         new_buf = state.buf
         den_ready = None
-        if use_buf:
+        if plan.use_buf:
             t_f = t.astype(jnp.float32)
             num_ready, den_ready, popped = async_mod.buffer_pop_ready(
                 state.buf, t_f, ctx.stale_alpha)
@@ -1094,7 +1168,7 @@ def make_round_step(cfg, cohort: int):
             agg_buf = (num_on + num_ready) \
                 / jnp.maximum(den_on + den_ready, DENOM_EPS)
             use_ready = den_ready > 0.0
-            if traced_srv:
+            if plan.traced_srv:
                 use_ready = use_ready & is_async
             agg = jnp.where(use_ready, agg_buf, agg)
             # in-flight candidates: the debias-scaled loss-masked
@@ -1108,7 +1182,7 @@ def make_round_step(cfg, cohort: int):
                 loss_rate=lr_deb, mult=mult)
             coord_mask = jnp.repeat(loss_mask, F, axis=1)[:, :D_up]
             base_rows = flat + state.ef_mem[ids] if ef else flat
-            if use_faults:
+            if plan.use_faults:
                 # the buffer refuses to launder corrupted data: the
                 # norm clip applies to buffered contributions too, a
                 # quarantined arrival (any bad delivered packet) is
@@ -1122,9 +1196,9 @@ def make_round_step(cfg, cohort: int):
                     scr_on & ~jnp.isfinite(base_rows), 0.0, base_rows)
             contrib = base_rows * coord_mask * q_full[:, None]
             cand_live = (lateness > 0.0) & (lateness < MAX_LATENESS)
-            if use_faults:
+            if plan.use_faults:
                 cand_live = cand_live & ~(scr_on & (rob.qcnt > 0.0))
-            if traced_srv:
+            if plan.traced_srv:
                 cand_live = cand_live & is_async
             new_buf = async_mod.buffer_insert(
                 popped, contrib, t_f + lateness, w_agg, lateness,
@@ -1153,7 +1227,7 @@ def make_round_step(cfg, cohort: int):
                 + cfg.pfedme_beta * agg
         else:  # fedavg / perfedavg: weighted mean of uploaded models
             new_vec = agg
-        if nonsync:
+        if plan.nonsync:
             # empty server step (no on-time, no grace, nothing due
             # from the buffer): the update is the identity, never a
             # division-by-zero and never a zeroed model (fedavg's
@@ -1163,7 +1237,7 @@ def make_round_step(cfg, cohort: int):
             den_tot = w_up.sum() if den_ready is None \
                 else w_up.sum() + den_ready
             has_arrivals = den_tot > 0.0
-            if traced_srv:
+            if plan.traced_srv:
                 has_arrivals = has_arrivals | is_sync
             new_vec = jnp.where(has_arrivals, new_vec, old_vec)
         new_params = unflatten_like(new_vec, params)
@@ -1183,27 +1257,27 @@ def make_round_step(cfg, cohort: int):
 
         # selection score memory: scatter this round's cohort stats for
         # the NEXT round's gradient_norm / loss_aware scores
-        gnorm_new = state.gnorm_mem.at[ids].set(ssq) if need_gnorm \
+        gnorm_new = state.gnorm_mem.at[ids].set(ssq) if plan.need_gnorm \
             else state.gnorm_mem
         loss_new = state.loss_mem.at[ids].set(aux["loss0"]) \
-            if need_loss else state.loss_mem
+            if plan.need_loss else state.loss_mem
         stale_new = state.stale_mem.at[ids].set(lateness) \
-            if need_stale and use_dl else state.stale_mem
+            if plan.need_stale and plan.use_dl else state.stale_mem
         # fault-model memories: the echo memory records what each
         # client GENUINELY computed (the replay source), the
         # reputation memory accumulates this round's quarantined
         # fraction for the reputation_aware policy
         echo_new = state.echo_mem.at[ids].set(flat_clean) \
-            if use_faults else state.echo_mem
+            if plan.use_faults else state.echo_mem
         rep_new = state.rep_mem.at[ids].add(rob.qcnt / P) \
-            if need_rep else state.rep_mem
+            if plan.need_rep else state.rep_mem
 
         # stale-parameter fallback memory: after this round, the
         # client's local model IS eff_vec (received coords fresh, lost
         # coords whatever it already had) — that's what a re-selected
         # client resumes from next time its downlink drops.
         stale_model_new = state.stale_model.at[ids].set(eff_vec) \
-            if (use_down and down_stale) else state.stale_model
+            if (plan.use_down and plan.down_stale) else state.stale_model
         # adaptive loss-budget controller: close the loop on the
         # REALIZED pre-recovery loss and the update-norm divergence
         # signal. The per-client policy used THIS round was read from
@@ -1212,7 +1286,7 @@ def make_round_step(cfg, cohort: int):
         # drives the NEXT selection of this client.
         bud_level_new, bud_loss_new = state.bud_level, state.bud_loss
         n_esc = lv = None
-        if use_bud:
+        if plan.use_bud:
             lv, ema_new, n_esc = bud_mod.controller_update(
                 state.bud_level[ids], state.bud_loss[ids], realized_c,
                 ssq, budget=ctx.bud_budget, beta=ctx.bud_ema,
@@ -1222,11 +1296,11 @@ def make_round_step(cfg, cohort: int):
 
         stage("telemetry")
         logs = {"loss": aux["loss0"].mean(), "ids": ids}
-        if use_faults:
+        if plan.use_faults:
             # per-cohort-slot quarantined-packet counts — the
             # robustness analyses' observability signal
             logs["quarantine"] = rob.qcnt
-        if use_dl:
+        if plan.use_dl:
             # effective per-cohort-slot arrival weight (1 = landed on
             # time at full weight, 0 = dropped): the participation
             # signal the fairness analyses read.
@@ -1237,26 +1311,26 @@ def make_round_step(cfg, cohort: int):
         # per-client aggregates update in the carry. Reads only
         # signals the round already computed — never the math.
         new_tele = state.tele
-        if tele_on:
+        if plan.tele_on:
             tele_scale = uplink_ops.debias_client_scale(
                 w_agg, mode=debias, kept=kept, sufficient=suff,
                 loss_rate=lr_deb, mult=mult)
             tlogs, new_tele = tele_mod.round_telemetry(
-                tele_cfg, state.tele, ids=ids, n_clients=N,
+                cfg.telemetry, state.tele, ids=ids, n_clients=N,
                 pkt_mask=pkt_mask, loss_mask=loss_mask,
                 old_vec=old_vec, new_vec=new_vec, scale=tele_scale,
                 logbw=ctx.sel_logbw
                 if ctx.sel_logbw.shape[0] == N else None,
                 ef_new_rows=new_ef_rows if ef else None,
-                arrival=arrival if use_dl else None,
-                lateness=lateness if use_dl else None,
-                qcnt=rob.qcnt if use_faults else None,
-                buf_due=new_buf.due if use_buf else None,
+                arrival=arrival if plan.use_dl else None,
+                lateness=lateness if plan.use_dl else None,
+                qcnt=rob.qcnt if plan.use_faults else None,
+                buf_due=new_buf.due if plan.use_buf else None,
                 buf_empty_due=async_mod.EMPTY_DUE,
                 down_frac=dn_frac,
                 fec_frac=fec_frac, arq_frac=arq_frac,
                 bud_escal=n_esc,
-                bud_level=lv.mean() if use_bud else None)
+                bud_level=lv.mean() if plan.use_bud else None)
             logs.update(tlogs)
         new_state = EngineState(new_params, new_ef, c_global_new,
                                 c_i_new, lam_new,
@@ -1269,6 +1343,17 @@ def make_round_step(cfg, cohort: int):
         return new_state, logs
 
     return step
+
+
+def fetch_logs(logs) -> Dict[str, np.ndarray]:
+    """A block's logs on the host in one fetch: every log's copy is
+    queued behind the program before the host waits on any, so the
+    block pays one round trip, not one per log. No wait of its own
+    either: a wait before the copies starts them only once the host has
+    seen the program end (about 0.13 ms a block on a TPU v5e)."""
+    for v in logs.values():
+        v.copy_to_host_async()
+    return {k: np.asarray(v) for k, v in logs.items()}
 
 
 def tree_bytes(tree) -> int:
@@ -1316,80 +1401,18 @@ class RoundScanEngine:
         if n_eligible == 0:
             raise ValueError("no eligible clients")
         self.cohort = min(cfg.clients_per_round, n_eligible)
-        self.eligible = jnp.asarray(np.asarray(eligible, bool))
-        self.sufficient = jnp.asarray(
-            np.asarray(sufficient, np.float32))
-        if cfg.tra.per_client_loss:
-            if packet_loss is None:
-                raise ValueError("tra.per_client_loss needs the trace "
-                                 "draw (pass nets.packet_loss)")
-            loss_rate = jnp.asarray(np.asarray(packet_loss, np.float32))
-        else:
-            loss_rate = jnp.float32(cfg.tra.loss_rate)
-        if (cfg.netsim.bw_ar1 or cfg.netsim.deadline) \
-                and upload_mbps is None:
-            raise ValueError("netsim bandwidth/deadline models need "
-                             "the trace draw (pass nets.upload_mbps)")
-        if (cfg.sel.traced or cfg.sel.policy == "bandwidth_threshold") \
-                and upload_mbps is None:
-            raise ValueError(
-                "the bandwidth_threshold selection score (and the "
-                "traced policy family, which includes it) needs the "
-                "trace draw (pass nets.upload_mbps)")
+        self.plan = RoundPlan.of(cfg, self.cohort)
+        self.plan.check_draws(packet_loss, upload_mbps)
         self._upload_mbps = None if upload_mbps is None \
             else np.asarray(upload_mbps, np.float32)
-        ns = cfg.netsim
-        sel = cfg.sel
-        srv = cfg.srv
-        flt = cfg.faults
-        dfn = cfg.defense
-        self.ctx = ScenarioCtx(
-            base_key=jax.random.PRNGKey(cfg.seed),
-            loss_rate=loss_rate,
-            eligible=self.eligible,
-            sufficient=self.sufficient,
-            data=self.dd,
-            burst_len=jnp.float32(ns.burst_len),
-            good_loss=jnp.float32(ns.good_loss),
-            bad_loss=jnp.float32(ns.bad_loss),
-            bw_rho=jnp.float32(ns.bw_rho),
-            deadline_s=jnp.float32(ns.deadline_s),
-            sel_threshold=jnp.float32(sel.threshold_mbps),
-            sel_temp=jnp.float32(sel.temperature),
-            sel_explore=jnp.float32(sel.explore),
-            sel_policy=jnp.asarray(sel_mod.policy_onehot(sel.policy)),
-            sel_logbw=log_upload_speeds(self._upload_mbps)
-            if self._upload_mbps is not None
-            else jnp.zeros((0,), jnp.float32),
-            srv_mode=jnp.asarray(async_mod.mode_onehot(srv.mode)),
-            stale_alpha=jnp.float32(srv.staleness_alpha),
-            grace_s=jnp.float32(srv.grace_s),
-            f_corrupt=jnp.float32(flt.corrupt_rate),
-            f_cscale=jnp.float32(flt.corrupt_scale),
-            f_bitflip=jnp.float32(flt.bitflip_rate),
-            f_fail=jnp.float32(flt.fail_rate),
-            f_flip=jnp.float32(flt.flip_rate),
-            f_echo=jnp.float32(flt.echo_rate),
-            d_screen=jnp.float32(1.0 if dfn.screen else 0.0),
-            d_clip=jnp.float32(faults_mod.clip_knob(dfn)),
-            d_trim=jnp.float32(1.0 if dfn.trim else 0.0),
-            down_loss=jnp.float32(ns.down_loss),
-            down_deadline_s=jnp.float32(ns.down_deadline_s),
-            rec_policy=jnp.asarray(
-                rec_mod.recovery_onehot(cfg.recovery.policy)),
-            rec_retries=jnp.float32(cfg.recovery.retries),
-            rec_backoff=jnp.float32(cfg.recovery.backoff),
-            bud_budget=jnp.float32(cfg.lossbudget.budget),
-            bud_ema=jnp.float32(cfg.lossbudget.ema),
-            bud_div=jnp.float32(cfg.lossbudget.div_gate),
-            frozen=frozen)
-        self._step, self._single, self._block = _cached_jits(
-            cfg, self.cohort)
+        self.ctx = scenario_ctx(cfg, eligible, sufficient, self.dd,
+                                packet_loss, self._upload_mbps, frozen)
+        self._step, self._single, self._block = _cached_jits(self.plan)
         self._block.count(resident=tree_bytes(frozen))
 
     # -- state --------------------------------------------------------------
     def init_state(self, params) -> EngineState:
-        return init_engine_state(self.cfg, params, self.n_clients,
+        return init_engine_state(self.plan, params, self.n_clients,
                                  base_key=self.ctx.base_key,
                                  loss_rate=self.ctx.loss_rate,
                                  upload_mbps=self._upload_mbps)
@@ -1408,15 +1431,8 @@ class RoundScanEngine:
             with tele_mod.Span("fl.block.args"):
                 ts = jnp.arange(t0, t0 + k, dtype=jnp.int32)
             state, logs = self._block(self.ctx, state, ts)
-            # one fetch: every log's copy is queued behind the program
-            # before the host waits on any, so the block pays one round
-            # trip, not one per log. No wait of its own either: a wait
-            # before the copies starts them only once the host has seen
-            # the program end (about 0.13 ms a block on a TPU v5e)
             with tele_mod.Span("fl.block.copy"):
-                for v in logs.values():
-                    v.copy_to_host_async()
-                out = {k_: np.asarray(v) for k_, v in logs.items()}
+                out = fetch_logs(logs)
             cfg = self.cfg
             self._block.count(d2h=sum(v.nbytes for v in out.values()),
                               arrays=len(out),

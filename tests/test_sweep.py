@@ -19,11 +19,15 @@ from jax.flatten_util import ravel_pytree
 
 from repro.core.engine import fused_debias_aggregate
 from repro.core.server import FederatedServer, FLConfig, run_grid
-from repro.core.sweep import SweepEngine
+from repro.core.async_agg import AsyncConfig
+from repro.core.selection import SelectionConfig
+from repro.core.sweep import SweepEngine, scenario_from_config
 from repro.core.tra import TRAConfig
 from repro.data.synthetic import (generate_synthetic,
                                   stage_scenarios_on_device)
 from repro.kernels.tra_agg.ops import DEBIAS_MODES, tra_aggregate_packed
+from repro.netsim import DefenseConfig, FaultConfig, NetSimConfig
+from repro.netsim.recovery import RecoveryConfig
 from repro.network.trace import ClientNetworks
 
 N_CLIENTS = 20
@@ -154,6 +158,81 @@ def test_sweep_rejects_mixed_static_grid(data, nets):
     with pytest.raises(ValueError, match="datasets"):
         SweepEngine.from_configs(
             [_cfg(seed=s) for s in range(3)], [data, data], nets)
+
+
+def _full_single_recipe(seed, loss_rate, corrupt_rate, trim, policy):
+    """The defended cell of the corruption-tolerance recipe with the
+    traced uplink FEC family (the benchmark's full.single program)."""
+    return FLConfig(
+        n_rounds=4, clients_per_round=12, local_steps=4, batch_size=16,
+        eval_every=100, seed=seed,
+        tra=TRAConfig(loss_rate=loss_rate, packet_floats=256),
+        netsim=NetSimConfig(channel="gilbert_elliott", burst_len=8.0,
+                            deadline=True, deadline_s=60.0),
+        faults=FaultConfig(enabled=True, corrupt_rate=corrupt_rate,
+                           corrupt_scale=0.5, fail_rate=0.1),
+        defense=DefenseConfig(screen=True, clip=True, clip_norm=20.0,
+                              trim=trim, trim_k=2),
+        recovery=RecoveryConfig(policy=policy, traced=True, group=8,
+                                retries=2.0, backoff=1.0))
+
+
+def _traced_sel_async(seed, policy, mode, alpha):
+    return _cfg(seed=seed, loss_rate=0.2 + 0.1 * seed,
+                sel=SelectionConfig(policy=policy, traced=True),
+                netsim=NetSimConfig(channel="gilbert_elliott",
+                                    deadline=True, deadline_s=30.0),
+                srv=AsyncConfig(mode=mode, traced=True,
+                                staleness_alpha=alpha))
+
+
+CTX_GRIDS = {
+    "fedavg": lambda: [_cfg(seed=0, loss_rate=0.1),
+                       _cfg(seed=3, loss_rate=0.3)],
+    "full_single": lambda: [
+        _full_single_recipe(0, 0.3, 0.1, True, "fec"),
+        _full_single_recipe(1, 0.2, 0.0, False, "arq")],
+    "traced_sel_async": lambda: [
+        _traced_sel_async(0, "uniform", "sync", 0.5),
+        _traced_sel_async(1, "bandwidth_threshold", "semi_sync", 1.0),
+        _traced_sel_async(2, "gradient_norm", "async", 0.25)],
+}
+
+
+@pytest.mark.parametrize("grid", sorted(CTX_GRIDS))
+def test_sweep_ctx_stacks_the_single_engine_ctx(grid, data, nets):
+    """Slice i of the sweep's context is what a single engine builds for
+    config i: every leaf's dtype, shape and value; the shared dataset
+    is the single engine's staged dataset."""
+    cfgs = CTX_GRIDS[grid]()
+    eng = SweepEngine.from_configs(cfgs, data, nets)
+    assert eng.ctx.frozen is None and not eng.data_batched
+    for i, cfg in enumerate(cfgs):
+        single = FederatedServer(cfg, data, nets).engine.ctx
+        got = jax.tree.map(lambda x: x[i], eng.ctx._replace(data=None))
+        want = single._replace(data=None)
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                                jax.tree.leaves(want)):
+            where = f"cell {i} {jax.tree_util.keystr(path)}"
+            assert (a.dtype, a.shape) == (b.dtype, b.shape), where
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=where)
+        for a, b in zip(jax.tree.leaves(eng.ctx.data),
+                        jax.tree.leaves(single.data)):
+            assert (a.dtype, a.shape) == (b.dtype, b.shape)
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_sweep_refuses_a_scenario_of_another_program(data, nets):
+    """A scenario built by hand for another program (here: the fault
+    model compiled in) is refused by name, before anything is staged."""
+    other = _cfg(seed=1, faults=FaultConfig(enabled=True))
+    with pytest.raises(ValueError, match="static") as err:
+        SweepEngine(_cfg(), [scenario_from_config(_cfg(), data, nets),
+                             scenario_from_config(other, data, nets)])
+    assert "scenario 1" in str(err.value)
+    assert "faults.enabled" in str(err.value)
 
 
 def test_stage_scenarios_on_device(data, data_het):

@@ -456,6 +456,49 @@ def test_run_block_starts_every_log_copy_before_converting(kw, keys,
 
 
 @pytest.mark.parametrize("kw,keys", LOG_KEYS)
+def test_sweep_run_block_starts_every_log_copy_before_converting(
+        kw, keys, data, nets):
+    """The sweep flushes like the single engine: every copy starts
+    before any converts, the block books one fetch, and the logs come
+    back scenario-major and bit-identical."""
+    reg = ProgramRegistry()
+    real_reg = tele_mod.REGISTRY
+    tele_mod.REGISTRY = reg
+    try:
+        eng = SweepEngine.from_configs(
+            [_cfg(rounds=2, seed=s, **kw) for s in (0, 1)], data, nets)
+        real, calls, seen = eng._block, [], {}
+
+        class Block:
+            def __call__(self, *args):
+                state, logs = real(*args)
+                seen.update(logs)
+                return state, {k: _RecordedLog(k, v, calls)
+                               for k, v in logs.items()}
+
+            def count(self, **kw):
+                real.count(**kw)
+
+        eng._block = Block()
+        _, out = eng.run_block(eng.init_states(), 0, 2)
+    finally:
+        tele_mod.REGISTRY = real_reg
+    assert set(out) == set(seen) == keys
+    starts = [i for i, (op, _) in enumerate(calls) if op == "start"]
+    converts = [i for i, (op, _) in enumerate(calls) if op == "convert"]
+    assert sorted(k for op, k in calls if op == "start") == sorted(keys)
+    assert len(converts) == len(keys) and max(starts) < min(converts)
+    for k, v in seen.items():
+        ref = np.moveaxis(np.asarray(v), 0, 1)
+        assert (out[k].dtype, out[k].shape) == (ref.dtype, ref.shape)
+        assert out[k].shape[:2] == (2, 2)
+        assert out[k].tobytes() == ref.tobytes()
+    sw = next(st for st in reg._stats.values() if st.kind == "sweep")
+    assert (sw.d2h_fetches, sw.d2h_arrays) == (1, len(keys))
+    assert sw.d2h_bytes == sum(v.nbytes for v in out.values())
+
+
+@pytest.mark.parametrize("kw,keys", LOG_KEYS)
 def test_run_block_counts_one_fetch_a_block(kw, keys, data, nets):
     reg = ProgramRegistry()
     real = tele_mod.REGISTRY
